@@ -11,6 +11,7 @@ import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import sys, time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+from repro.core.compat import make_mesh
 
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import (cannon_matmul, cannon_matmul_25d, cannon_matmul_pallas,
@@ -26,7 +27,7 @@ B = jnp.array(np.random.RandomState(1).randn(n, n), jnp.float32)
 want = np.asarray(A @ B)
 
 # square 2x2 grid (4 of the 8 devices) and rectangular 2x4 grid (all 8)
-mesh_sq = jax.make_mesh((2, 2), ("x", "y"), devices=jax.devices()[:4])
+mesh_sq = make_mesh((2, 2), ("x", "y"), devices=jax.devices()[:4])
 mesh_rc = make_grid_mesh((2, 4), ("x", "y"))
 
 for name, mesh in (("2x2", mesh_sq), ("2x4", mesh_rc)):

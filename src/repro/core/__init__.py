@@ -9,7 +9,6 @@ from .dseq import (DSeq, spmd, reduce_d, shift_d, all_gather_d, all_to_all_d,
                    all_gather_ring_d)
 from .grid import GridN, Grid2D, Grid3D, make_grid_mesh
 from . import costmodel
-from .compat import abstract_mesh
 from .dns_matmul import dns_matmul, generic_matmul, dns_matmul_pallas
 from .summa import (summa_matmul, cannon_matmul, summa_matmul_pallas,
                     cannon_matmul_pallas)

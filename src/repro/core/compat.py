@@ -1,17 +1,25 @@
-"""Version compatibility shims for the jax API surface this repo targets.
+"""The two mesh-level spellings the whole repo shares.
 
-The codebase is written against the modern spelling (``jax.shard_map`` with
-``check_vma`` / ``axis_names``, ``lax.axis_size``, size-and-names
-``AbstractMesh``).  Older installed versions (0.4.x) expose the same
-functionality under different names; everything version-sensitive funnels
-through this module so call sites stay on one spelling.
+``make_mesh`` pins every axis to ``AxisType.Auto``: the code is written for
+GSPMD-style propagation (``with_sharding_constraint`` hints, partial-manual
+``shard_map``), and ``jax.make_mesh`` now defaults to ``Explicit`` axes,
+which refuse both.  ``shard_map`` keeps the repo's ``check`` /
+``axis_names`` keywords on one spelling.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Iterable
+from typing import Any, Callable, Iterable, Sequence
 
 import jax
-from jax import lax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axis_names: Sequence[str], *,
+              devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes (every mesh in the repo)."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def shard_map(
@@ -23,45 +31,8 @@ def shard_map(
     axis_names: Iterable[str] | None = None,
     check: bool = False,
 ) -> Callable:
-    """``jax.shard_map`` with partial-manual support on both API generations.
-
-    ``axis_names`` lists the *manual* axes (modern spelling); on 0.4.x it is
-    translated to the complementary ``auto`` set.  ``check`` maps to
-    ``check_vma`` (new) / ``check_rep`` (old).
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check, **kw,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check, **kw,
-    )
-
-
-def axis_size(axis: str | tuple) -> int:
-    """Static size of a mesh axis from inside an SPMD body.
-
-    ``lax.axis_size`` where available; otherwise ``psum`` of a Python scalar,
-    which constant-folds to the concrete group size.
-    """
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
-def abstract_mesh(shape: tuple, axis_names: tuple) -> "jax.sharding.AbstractMesh":
-    """``AbstractMesh`` across the (name, size)-pairs / sizes-plus-names split."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(zip(axis_names, shape)))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axis_names))
+    """``jax.shard_map``; ``axis_names`` lists the *manual* axes (all of
+    them when None), ``check`` maps to ``check_vma``."""
+    kw = {} if axis_names is None else {"axis_names": frozenset(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check, **kw)

@@ -14,7 +14,6 @@ All operate on logically (n, n) matrices decomposed into q×q blocks.
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Callable
 
 import jax
@@ -89,10 +88,10 @@ def generic_matmul(A: jax.Array, B: jax.Array, mesh: jax.sharding.Mesh,
     return jnp.concatenate(rows, axis=0)
 
 
-def dns_matmul_pallas(A: jax.Array, B: jax.Array, mesh: jax.sharding.Mesh,
-                      *, interpret: bool = True) -> jax.Array:
+def dns_matmul_pallas(A: jax.Array, B: jax.Array,
+                      mesh: jax.sharding.Mesh) -> jax.Array:
     """Algorithm 2 with the Pallas MXU kernel as the local multiply."""
     from repro.kernels.ops import matmul as pallas_matmul
 
     return dns_matmul(A, B, mesh,
-                      local_matmul=partial(pallas_matmul, interpret=interpret))
+                      local_matmul=pallas_matmul)

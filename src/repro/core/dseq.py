@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .compat import axis_size, shard_map as _shard_map
+from .compat import shard_map as _shard_map
 
 Pytree = Any
 
@@ -76,7 +76,7 @@ def reduce_d(x: Pytree, op: Callable | str, axis: str, *, root: int | None = Non
         idx = lax.axis_index(axis)
         return jax.tree.map(lambda l: jnp.where(idx == root, l, jnp.zeros_like(l)), out)
 
-    p = axis_size(axis)
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     rounds = max(1, math.ceil(math.log2(p))) if p > 1 else 0
     for r in range(rounds):
@@ -106,7 +106,7 @@ def reduce_d(x: Pytree, op: Callable | str, axis: str, *, root: int | None = Non
 
 def shift_d(x: Pytree, delta: int, axis: str) -> Pytree:
     """FooPar ``shiftD``: cyclic shift by ``delta`` — Θ(t_s + t_w m)."""
-    p = axis_size(axis)
+    p = lax.axis_size(axis)
     d = delta % p
     if d == 0:
         return x
@@ -153,7 +153,7 @@ def scan_d(x: Pytree, axis: str, op: Callable | None = None, *,
     """
     op = op or (lambda a, b: a + b)
     idx = lax.axis_index(axis)
-    p = axis_size(axis)
+    p = lax.axis_size(axis)
     acc = x
     for r in range(max(0, math.ceil(math.log2(p)))):
         stride = 1 << r
@@ -191,7 +191,7 @@ def reduce_scatter_d(x: Pytree, op: Callable | str, axis: str) -> Pytree:
             x,
         )
 
-    p = axis_size(axis)
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     ring = [(i, (i + 1) % p) for i in range(p)]
     for l in jax.tree.leaves(x):
@@ -233,7 +233,7 @@ def all_gather_ring_d(x: Pytree, axis: str) -> Pytree:
     the block received at each step — Θ((t_s + t_w m)(p-1)), identical in Θ
     to the native all-gather but expressed in the algebra (and usable with
     compute overlapped between steps, as in pipelined SUMMA)."""
-    p = axis_size(axis)
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     parts = [jax.tree.map(lambda l: l, x)]
     buf = x
@@ -310,7 +310,7 @@ class DSeq:
     # -- introspection -----------------------------------------------------
     @property
     def size(self) -> int:
-        return axis_size(self.axis)
+        return lax.axis_size(self.axis)
 
     @property
     def rank(self) -> jax.Array:

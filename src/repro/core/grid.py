@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .compat import axis_size
+from .compat import make_mesh
 from .dseq import DSeq, apply_d, reduce_d, ring_shift_d, shift_d
 
 Pytree = Any
@@ -52,7 +52,7 @@ class RingBcast:
 
     def step(self) -> "RingBcast":
         """Advance one nearest-neighbour hop (``ring_shift_d``)."""
-        p = axis_size(self.axis)
+        p = lax.axis_size(self.axis)
         if self.hops >= p - 1:
             return self
         idx = lax.axis_index(self.axis)
@@ -68,7 +68,7 @@ class RingBcast:
 
     @property
     def done(self) -> bool:
-        return self.hops >= axis_size(self.axis) - 1
+        return self.hops >= lax.axis_size(self.axis) - 1
 
     @property
     def value(self) -> Pytree:
@@ -96,7 +96,7 @@ class GridN:
 
     @property
     def shape(self) -> Tuple[int, ...]:
-        return tuple(axis_size(a) for a in self.axes)
+        return tuple(lax.axis_size(a) for a in self.axes)
 
     def mapD(self, f: Callable[..., Pytree]) -> Pytree:
         """Each process computes ``f(*coords)`` — the paper's
@@ -223,4 +223,4 @@ class Grid3D(GridN):
 def make_grid_mesh(shape: Sequence[int], axes: Sequence[str] | None = None) -> jax.sharding.Mesh:
     """Build a device mesh for an N-d grid on the available devices."""
     axes = tuple(axes) if axes is not None else tuple("xyzw"[: len(shape)])
-    return jax.make_mesh(tuple(shape), axes)
+    return make_mesh(shape, axes)

@@ -51,7 +51,6 @@ comparison in ``costmodel.isoefficiency_matmul_*``.
 from __future__ import annotations
 
 import math
-from functools import partial
 from typing import Callable, List
 
 import jax
@@ -209,20 +208,20 @@ def cannon_matmul(A: jax.Array, B: jax.Array, mesh: jax.sharding.Mesh,
     return fn(A, B)
 
 
-def summa_matmul_pallas(A: jax.Array, B: jax.Array, mesh: jax.sharding.Mesh,
-                        *, interpret: bool = True) -> jax.Array:
+def summa_matmul_pallas(A: jax.Array, B: jax.Array,
+                        mesh: jax.sharding.Mesh) -> jax.Array:
     """SUMMA with the accumulate-in-place Pallas MXU kernel (the per-panel
     ``C += A_k B_k`` never materializes a separate product temporary)."""
     from repro.kernels.ops import matmul_acc
 
     return summa_matmul(A, B, mesh,
-                        local_matmul_acc=partial(matmul_acc, interpret=interpret))
+                        local_matmul_acc=matmul_acc)
 
 
-def cannon_matmul_pallas(A: jax.Array, B: jax.Array, mesh: jax.sharding.Mesh,
-                         *, interpret: bool = True) -> jax.Array:
+def cannon_matmul_pallas(A: jax.Array, B: jax.Array,
+                         mesh: jax.sharding.Mesh) -> jax.Array:
     """Cannon with the accumulate-in-place Pallas MXU kernel."""
     from repro.kernels.ops import matmul_acc
 
     return cannon_matmul(A, B, mesh,
-                         local_matmul_acc=partial(matmul_acc, interpret=interpret))
+                         local_matmul_acc=matmul_acc)

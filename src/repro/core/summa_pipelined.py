@@ -27,7 +27,6 @@ temporary.
 from __future__ import annotations
 
 import math
-from functools import partial
 from typing import Callable
 
 import jax
@@ -171,20 +170,18 @@ def cannon_matmul_25d(A: jax.Array, B: jax.Array, mesh: jax.sharding.Mesh,
 
 
 def summa_matmul_pipelined_pallas(A: jax.Array, B: jax.Array,
-                                  mesh: jax.sharding.Mesh, *,
-                                  interpret: bool = True) -> jax.Array:
+                                  mesh: jax.sharding.Mesh) -> jax.Array:
     """Pipelined SUMMA with the accumulate-in-place Pallas MXU kernel."""
     from repro.kernels.ops import matmul_acc
 
     return summa_matmul_pipelined(
-        A, B, mesh, local_matmul_acc=partial(matmul_acc, interpret=interpret))
+        A, B, mesh, local_matmul_acc=matmul_acc)
 
 
 def cannon_matmul_25d_pallas(A: jax.Array, B: jax.Array,
-                             mesh: jax.sharding.Mesh, *,
-                             interpret: bool = True) -> jax.Array:
+                             mesh: jax.sharding.Mesh) -> jax.Array:
     """2.5D Cannon with the accumulate-in-place Pallas MXU kernel."""
     from repro.kernels.ops import matmul_acc
 
     return cannon_matmul_25d(
-        A, B, mesh, local_matmul_acc=partial(matmul_acc, interpret=interpret))
+        A, B, mesh, local_matmul_acc=matmul_acc)

@@ -7,6 +7,11 @@ prefetch (``pltpu.PrefetchScalarGridSpec``): the table row is available
 before the body runs, so each page's BlockSpec ``index_map`` picks the
 physical arena block to DMA — the gather costs no extra kernel pass.
 
+The arenas are laid out ``(N, Hkv, block, hd)``, so one (page, kv-head)
+tile is a whole ``(block, hd)`` slab: the last two dims of every staged
+block are the array's own, which the TPU's tiling accepts for any page
+size and head count.
+
 Grid (B, Hkv, P): each (request, kv-head) pair owns a run of the innermost
 page dimension; the online-softmax statistics (m, l) and the f32 output
 accumulator for its ``rep`` grouped query heads persist in VMEM scratch
@@ -17,11 +22,10 @@ so the skip saves real time: a request occupying 3 of P=64 table slots pays
 for 3 page reads, not 64); the partially-filled last page is masked
 per-position.
 
-``paged_attention`` is the public entry: on TPU it lowers the kernel, off
-TPU (or if lowering fails) it falls back to the pure-jnp reference in
-``ref.py`` — the same auto-dispatch pattern as ``kernels/ops.py``, except
-the fallback is the *reference* rather than interpret-mode Pallas, because
-the serving engine calls this once per decode tick and interpret-mode
+``paged_attention`` is the public entry: on TPU it lowers the kernel (a
+lowering failure surfaces), elsewhere it runs the pure-jnp reference in
+``ref.py`` — the reference rather than interpret-mode Pallas, because the
+serving engine calls this once per decode tick and interpret-mode
 evaluation is a correctness harness, not a serving path.
 """
 from __future__ import annotations
@@ -31,14 +35,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import ref
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:                                     # pallas needs a recent jaxlib;
-    from jax.experimental import pallas as pl            # gate, don't require
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except ImportError:                      # pragma: no cover - container has it
-    _HAS_PALLAS = False
+from . import ref
 
 NEG_INF = -1e30
 
@@ -63,8 +63,8 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(live)
     def _attend():
         q = q_ref[0, 0].astype(jnp.float32) * scale       # (rep, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)         # (block, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)               # (block, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (rep, block)
         kpos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -92,10 +92,10 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
                            lengths: jax.Array, *, scale: float | None = None,
                            interpret: bool = False) -> jax.Array:
     """Layouts as ``ref.paged_attention``: q (B, Hkv, rep, hd); arenas
-    (N, block, Hkv, hd); block_tables (B, P) int32 (-1 = unallocated);
+    (N, Hkv, block, hd); block_tables (B, P) int32 (-1 = unallocated);
     lengths (B,) int32 valid tokens."""
     b, hkv, rep, hd = q.shape
-    n, blk, hkv2, hd2 = k_pages.shape
+    n, hkv2, blk, hd2 = k_pages.shape
     assert (hkv, hd) == (hkv2, hd2), (q.shape, k_pages.shape)
     pages = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / (hd ** 0.5)
@@ -110,10 +110,10 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
             # the page gather: the arena block to stage is *named by the
             # prefetched table*, clamped so dead (-1) entries stay in range
             # (their page is skipped in the body)
-            pl.BlockSpec((1, blk, 1, hd),
-                         lambda bb, h, p, tbl, lens: (jnp.maximum(tbl[bb, p], 0), 0, h, 0)),
-            pl.BlockSpec((1, blk, 1, hd),
-                         lambda bb, h, p, tbl, lens: (jnp.maximum(tbl[bb, p], 0), 0, h, 0)),
+            pl.BlockSpec((1, 1, blk, hd),
+                         lambda bb, h, p, tbl, lens: (jnp.maximum(tbl[bb, p], 0), h, 0, 0)),
+            pl.BlockSpec((1, 1, blk, hd),
+                         lambda bb, h, p, tbl, lens: (jnp.maximum(tbl[bb, p], 0), h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, rep, hd), lambda bb, h, p, tbl, lens: (bb, h, 0, 0)),
         scratch_shapes=[
@@ -131,18 +131,10 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    block_tables: jax.Array, lengths: jax.Array, *,
-                    backend: str | None = None) -> jax.Array:
-    """Auto-dispatched paged decode attention (the model decode path's
-    entry).  backend: "pallas" | "ref" | None (auto: pallas on TPU, the
-    jnp reference elsewhere — the lowering fallback)."""
-    if backend is None:
-        backend = "pallas" if (_HAS_PALLAS and
-                               jax.default_backend() == "tpu") else "ref"
-    if backend == "ref":
-        return ref.paged_attention(q, k_pages, v_pages, block_tables, lengths)
-    try:
+                    block_tables: jax.Array, lengths: jax.Array) -> jax.Array:
+    """Paged decode attention, the model decode path's entry: the Pallas
+    kernel on TPU, the jnp reference elsewhere."""
+    if jax.default_backend() == "tpu":
         return paged_attention_pallas(q, k_pages, v_pages, block_tables,
                                       lengths)
-    except Exception:                    # lowering/compile failure -> oracle
-        return ref.paged_attention(q, k_pages, v_pages, block_tables, lengths)
+    return ref.paged_attention(q, k_pages, v_pages, block_tables, lengths)

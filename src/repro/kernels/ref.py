@@ -21,13 +21,23 @@ def minplus(a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.min(a[:, :, None] + b[None, :, :], axis=1)
 
 
+def gather_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
+    """Dense ``(B, P*block, Hkv, hd)`` view of each request's page chain in
+    an ``(N, Hkv, block, hd)`` arena; -1 table entries clamp to block 0
+    (callers mask those positions)."""
+    b, p = block_tables.shape
+    _, hkv, blk, hd = pages.shape
+    g = pages[jnp.maximum(block_tables, 0)]              # (B, P, Hkv, blk, hd)
+    return g.swapaxes(2, 3).reshape(b, p * blk, hkv, hd)
+
+
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     block_tables: jax.Array, lengths: jax.Array) -> jax.Array:
     """Paged decode-attention oracle: one query token per request, K/V
     gathered through the block table.
 
     q: (B, Hkv, rep, hd) — grouped query heads (GQA: rep = Hq // Hkv).
-    k_pages, v_pages: (N, block, Hkv, hd) — the shared page arenas.
+    k_pages, v_pages: (N, Hkv, block, hd) — the shared page arenas.
     block_tables: (B, P) int32 — request b's logical page j lives in
     physical block ``block_tables[b, j]``; -1 marks an unallocated tail
     entry (its keys are masked, the gather clamps the index).
@@ -38,16 +48,13 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     softmax, probabilities cast back to q.dtype for the PV contraction) so
     the paged decode engine's greedy tokens match the end-aligned engine's.
     """
-    b, hkv, rep, hd = q.shape
-    n, blk, _, _ = k_pages.shape
-    p = block_tables.shape[1]
-    idx = jnp.maximum(block_tables, 0)                   # clamp -1 entries
-    k = k_pages[idx].reshape(b, p * blk, hkv, hd)        # (B, K, Hkv, hd)
-    v = v_pages[idx].reshape(b, p * blk, hkv, hd)
+    hd = q.shape[-1]
+    k = gather_pages(k_pages, block_tables)              # (B, K, Hkv, hd)
+    v = gather_pages(v_pages, block_tables)
     scale = 1.0 / math.sqrt(hd)
     s = jnp.einsum("bgrd,bkgd->bgrk", q * scale, k,
                    preferred_element_type=jnp.float32)
-    kpos = jnp.arange(p * blk)
+    kpos = jnp.arange(k.shape[1])
     mask = kpos[None, :] < lengths[:, None]              # (B, K)
     s = jnp.where(mask[:, None, None, :], s, -1e30)
     probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)

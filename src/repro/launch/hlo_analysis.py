@@ -106,8 +106,6 @@ def collective_stats(hlo_text: str, n_devices: int) -> Dict:
 def analyze_compiled(compiled, n_devices: int) -> Dict:
     """All dry-run artifacts for one cell: memory, flops, collectives."""
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):     # jax<=0.4.x wraps it in a list
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     txt = compiled.as_text()
     coll = collective_stats(txt, n_devices)

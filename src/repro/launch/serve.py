@@ -3,7 +3,10 @@
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b --requests 4 \
       --prompt-len 32 --gen 16 --slots 4 --stagger 2
 
-Runs a reduced config on CPU; the same driver serves the production mesh.
+Serves a reduced config by default (CPU-sized); ``--no-reduce`` serves the
+published widths (e.g. chatglm3-6b on one 16 GB TPU v5e chip).  Weights are
+random, drawn from ``--seed`` directly in the compute dtype on the device
+(``serving_params``): no f32 master copy exists on the serve path.
 Each prompt is prefilled in ONE fused cache-writing forward (recurrent
 families fall back to a per-token loop), then requests share a fixed slot
 pool: staggered arrivals are admitted into free slots mid-flight, finished
@@ -25,15 +28,29 @@ import argparse
 import jax
 
 from repro import configs
+from repro.config import ModelConfig
+from repro.launch.cache import use_compile_cache
 from repro.launch.scheduler import Scheduler, make_requests
 from repro.launch.train import reduced
 from repro.models import transformer as T
 from repro.parallel import planner
 
 
+def serving_params(cfg: ModelConfig, seed: int):
+    """Random serving weights drawn from ``seed``, created directly in the
+    compute dtype (``cfg.dtype``) by the jitted init on the default device.
+    Returns ``(serving_cfg, params)``; the config records the param dtype."""
+    cfg = cfg.replace(param_dtype=cfg.dtype)
+    return cfg, T.init(jax.random.PRNGKey(seed), cfg)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b")
+    ap.add_argument("--reduce", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the CPU-sized reduced config (default); "
+                         "--no-reduce serves the published widths")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
@@ -58,7 +75,8 @@ def main():
     ap.add_argument("--top-p", type=float, default=1.0,
                     help="nucleus sampling mass (only with --temperature > 0)")
     ap.add_argument("--seed", type=int, default=0,
-                    help="sampling stream seed (reproducible runs)")
+                    help="seed of the random weights and the sampling "
+                         "stream (reproducible runs)")
     args = ap.parse_args()
     if args.requests < 1 or args.gen < 1:
         ap.error(f"--requests and --gen must be >= 1 "
@@ -74,22 +92,25 @@ def main():
         ap.error("--prompt-len + --gen must be >= 2 (the slot pool needs a "
                  "cache of at least two positions)")
 
-    cfg = reduced(configs.get(args.arch))
+    use_compile_cache()
+    cfg = configs.get(args.arch)
+    if args.reduce:
+        cfg = reduced(cfg)
     if cfg.enc_dec:
         raise SystemExit("enc-dec serving: use examples/whisper_serve.py")
     if args.paged and not T.supports_paged(cfg):
         raise SystemExit(f"--paged needs a pure-attention no-SWA arch; "
                          f"{cfg.name} has pattern {cfg.block_pattern} "
                          f"(window={cfg.window})")
-    # single-host CPU layout as a first-class plan (the scheduler bridges it)
+    # single-device layout as a first-class plan (the scheduler bridges it)
     plan = planner.ParallelPlan(mesh_shape=(1, 1), fsdp_axes=(), tp=1,
                                 grad="none", remat="none")
-    params = T.init(jax.random.PRNGKey(0), cfg)
+    cfg, params = serving_params(cfg, args.seed)
 
     slots = 1 if args.naive else args.slots
     max_len = args.prompt_len + args.gen
     if not args.paged and cfg.window is not None and max_len > cfg.window:
-        raise SystemExit(f"prompt+gen {max_len} exceeds the reduced "
+        raise SystemExit(f"prompt+gen {max_len} exceeds the "
                          f"attention window {cfg.window} (end-aligned slots; "
                          f"--paged lifts the limit for no-SWA archs)")
     sched = Scheduler(cfg, plan, params, slots=slots, max_len=max_len,

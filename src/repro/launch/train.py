@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro import configs
 from repro.config import (ModelConfig, ParallelConfig, ShapeConfig, TrainConfig)
 from repro.data import make_batch_iterator
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.parallel import planner
 from repro.parallel import steps as S
@@ -72,6 +73,7 @@ def main():
     ap.add_argument("--model-parallel", type=int, default=1)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = configs.get(args.arch)
     if args.reduce:
         cfg = reduced(cfg)

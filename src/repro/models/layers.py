@@ -198,7 +198,7 @@ def attention(p: Params, x: jax.Array, positions: jax.Array, cfg: ModelConfig, *
     Decode: ``cache=(k, v)`` of length L; the new token's k/v are written at
     ``cache_pos`` (already ring-reduced for SWA), then q attends to the cache.
     Paged decode/prefill: ``block_tables`` given — ``cache`` is the shared
-    page *arena* ``(n_blocks, block, Hkv, hd)`` and each request reads/writes
+    page *arena* ``(n_blocks, Hkv, block, hd)`` and each request reads/writes
     through its block-table row (the page view; ``serving/kvcache.py`` owns
     the host-side allocation).
     Cross-attention (whisper): ``xattn_kv`` is the encoder output; keys/values
@@ -241,8 +241,8 @@ def attention(p: Params, x: jax.Array, positions: jax.Array, cfg: ModelConfig, *
         # the per-slot end-aligned row, so prompt+gen is bounded by pool
         # capacity, not slot length).  SWA rings and paging don't compose.
         assert cfg.window is None, "paged attention needs full (no-SWA) attention"
-        ck, cv = cache                    # (n_blocks, block, Hkv, hd) arenas
-        n_blocks, blk = ck.shape[0], ck.shape[1]
+        ck, cv = cache                    # (n_blocks, Hkv, block, hd) arenas
+        n_blocks, blk = ck.shape[0], ck.shape[2]
         if jnp.ndim(cache_pos) == 1:
             # decode: each request writes its token at page pos//block,
             # offset pos%block of its own chain; rows whose table entry is
@@ -250,8 +250,8 @@ def attention(p: Params, x: jax.Array, positions: jax.Array, cfg: ModelConfig, *
             pg, off = cache_pos // blk, cache_pos % blk
             entry = jnp.take_along_axis(block_tables, pg[:, None], axis=1)[:, 0]
             phys = jnp.where(entry >= 0, entry, n_blocks)
-            ck = ck.at[phys, off].set(k[:, 0].astype(ck.dtype), mode="drop")
-            cv = cv.at[phys, off].set(v[:, 0].astype(cv.dtype), mode="drop")
+            ck = ck.at[phys, :, off].set(k[:, 0].astype(ck.dtype), mode="drop")
+            cv = cv.at[phys, :, off].set(v[:, 0].astype(cv.dtype), mode="drop")
             from repro.kernels.paged_attention import paged_attention
             out = paged_attention(q[:, 0], ck, cv, block_tables,
                                   cache_pos + 1)[:, None]
@@ -274,11 +274,11 @@ def attention(p: Params, x: jax.Array, positions: jax.Array, cfg: ModelConfig, *
                               block_tables[0, jnp.minimum(pg, n_pages - 1)],
                               -1)
             phys = jnp.where(entry >= 0, entry, n_blocks)
-            ck = ck.at[phys, off].set(k[0].astype(ck.dtype), mode="drop")
-            cv = cv.at[phys, off].set(v[0].astype(cv.dtype), mode="drop")
-            idx = jnp.maximum(block_tables, 0)
-            out = _sdpa(q, ck[idx].reshape(b, -1, hkv, hd),
-                        cv[idx].reshape(b, -1, hkv, hd),
+            ck = ck.at[phys, :, off].set(k[0].astype(ck.dtype), mode="drop")
+            cv = cv.at[phys, :, off].set(v[0].astype(cv.dtype), mode="drop")
+            from repro.kernels.ref import gather_pages
+            out = _sdpa(q, gather_pages(ck, block_tables),
+                        gather_pages(cv, block_tables),
                         causal=True, window=None, q_offset=cache_pos)
         new_cache = (ck, cv)
     elif cache is not None:

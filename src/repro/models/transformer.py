@@ -128,7 +128,12 @@ def _block_apply(kind: str, p: Params, h: jax.Array, positions, cfg: ModelConfig
 # ---------------------------------------------------------------------------
 # Whole-model init
 # ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnames="cfg")
 def init(rng, cfg: ModelConfig) -> Params:
+    """Random params in ``cfg.param_dtype``, built in one compiled program
+    on the default device: the periods are drawn by a ``lax.map`` straight
+    into the stacked (leading ``n_periods``) leaves, so peak memory stays at
+    the parameter bytes plus one period's temporaries."""
     ks = jax.random.split(rng, 4)
     period = cfg.block_pattern
 
@@ -136,10 +141,7 @@ def init(rng, cfg: ModelConfig) -> Params:
         kr = jax.random.split(prng, len(period))
         return tuple(_block_init(k, kr[i], cfg) for i, k in enumerate(period))
 
-    period_rngs = jax.random.split(ks[0], cfg.n_periods)
-    # stack params over periods (leading axis = n_periods)
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *[one_period(r) for r in period_rngs]) \
-        if cfg.n_periods > 1 else jax.tree.map(lambda x: x[None], one_period(period_rngs[0]))
+    stacked = lax.map(one_period, jax.random.split(ks[0], cfg.n_periods))
 
     params: Params = {
         "embed": L.embed_init(ks[1], cfg),
@@ -258,14 +260,14 @@ def supports_paged(cfg: ModelConfig) -> bool:
 def init_paged_cache(cfg: ModelConfig, n_blocks: int, block: int,
                      dtype=jnp.bfloat16) -> Any:
     """Paged cache arena pytree, stacked over periods like ``init_cache``:
-    per attention layer one (K, V) pair of ``(n_blocks, block, kv_heads,
+    per attention layer one (K, V) pair of ``(n_blocks, kv_heads, block,
     hd)`` pages shared by every request (``serving.BlockPool`` hands out the
     blocks; requests address them through block tables)."""
     if not supports_paged(cfg):
         raise NotImplementedError(
             f"paged KV cache needs a pure-attention, no-SWA pattern; got "
             f"{cfg.block_pattern} (window={cfg.window})")
-    shp = (n_blocks, block, cfg.n_kv_heads, cfg.hd)
+    shp = (n_blocks, cfg.n_kv_heads, block, cfg.hd)
     percell = tuple({"attn": (jnp.zeros(shp, dtype), jnp.zeros(shp, dtype))}
                     for _ in cfg.block_pattern)
     return jax.tree.map(

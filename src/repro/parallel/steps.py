@@ -160,6 +160,13 @@ def make_train_step_zero(cfg: ModelConfig, pcfg: ParallelConfig,
     def train_step(state: Pytree, batch: Pytree) -> Tuple[Pytree, Pytree]:
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state["params"], batch)
+        scatter = to_shardings(scatter_specs(state["params"], cfg, ctx),
+                               ctx.mesh)
+        gather = to_shardings(param_specs(state["params"], cfg, ctx), ctx.mesh)
+        # the reduced grads live in the params' layout; without this pin the
+        # scatter layout propagates back into the backward pass and the
+        # partitioner re-orders the embedding's scatter-add sums
+        grads = lax.with_sharding_constraint(grads, gather)
         if pcfg.grad_barrier:
             grads = lax.optimization_barrier(grads)
         if pcfg.grad_dtype != "float32":
@@ -168,9 +175,6 @@ def make_train_step_zero(cfg: ModelConfig, pcfg: ParallelConfig,
         lr = optim.warmup_cosine(state["opt"]["step"], lr=tcfg.lr,
                                  warmup_steps=tcfg.warmup_steps,
                                  total_steps=tcfg.total_steps)
-        scatter = to_shardings(scatter_specs(state["params"], cfg, ctx),
-                               ctx.mesh)
-        gather = to_shardings(param_specs(state["params"], cfg, ctx), ctx.mesh)
         params, opt_state = optim.adamw_update_zero(
             grads, state["opt"], state["params"], scatter=scatter,
             gather=gather, lr=lr, b1=tcfg.b1, b2=tcfg.b2,

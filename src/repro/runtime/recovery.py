@@ -34,6 +34,7 @@ import jax
 import numpy as np
 
 from repro import checkpoint as ckpt
+from repro.core.compat import make_mesh
 
 
 class StepWatchdog:
@@ -65,7 +66,7 @@ class ElasticPlan:
         shape, axes = (data, self.model), ("data", "model")
         if devices is not None:
             devices = devices[: data * self.model]
-        return jax.make_mesh(shape, axes, devices=devices)
+        return make_mesh(shape, axes, devices=devices)
 
 
 @dataclass

@@ -13,7 +13,7 @@ Split of responsibilities (mirrors the slot engine's host/device split):
     per-request page chains, admission *reservations*, and the occupancy /
     fragmentation report.  It never touches device memory, so the scheduler
     can keep donating the device arena through its jitted steps.
-  * The device arena — one ``(n_periods, n_blocks, block, kv_heads, hd)``
+  * The device arena — one ``(n_periods, n_blocks, kv_heads, block, hd)``
     K and V pair per attention position in the block pattern — is built by
     ``models.transformer.init_paged_cache`` and threaded through the jitted
     decode / chunked-prefill steps exactly like the end-aligned cache.

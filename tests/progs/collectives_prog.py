@@ -15,11 +15,12 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
+from repro.core.compat import make_mesh
 from repro.core import spmd
 from repro.core.dseq import (all_gather_ring_d, reduce_scatter_d, ring_shift_d,
                              scan_d)
 
-MESHES = {p: jax.make_mesh((p,), ("x",), devices=jax.devices()[:p])
+MESHES = {p: make_mesh((p,), ("x",), devices=jax.devices()[:p])
           for p in (4, 8)}
 _cache = {}
 

@@ -9,6 +9,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
+from repro.core.compat import make_mesh
 from repro.core import DSeq, spmd, make_grid_mesh
 from repro.core.dseq import scan_d
 
@@ -53,7 +54,7 @@ y = spmd(body3, mesh, in_specs=P("x", None), out_specs=P("x", None))(
 np.testing.assert_allclose(np.asarray(y), np.asarray(jnp.arange(64.0).reshape(8, 8)).T)
 
 # non-power-of-two group (tree reduce remainder handling)
-mesh6 = jax.make_mesh((6,), ("x",), devices=jax.devices()[:6])
+mesh6 = make_mesh((6,), ("x",), devices=jax.devices()[:6])
 x6 = jnp.arange(6.0 * 3).reshape(6, 3)
 r6 = spmd(lambda xl: DSeq(xl[0], "x").reduceD(lambda a, b: a + b), mesh6,
           in_specs=P("x", None), out_specs=P(None))(x6)

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
+from repro.core.compat import make_mesh
 from repro.config import ModelConfig, MoEConfig
 from repro.models.moe import moe_init, moe_ffn, MeshCtx
 
@@ -21,7 +22,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (8, 4, 32))
 
 ref, ref_probs = moe_ffn(params, x, cfg, None)  # single-device oracle
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 ctx = MeshCtx(mesh=mesh, batch_axes=("data",), model_axis="model",
               fsdp_axes=("data",))
 

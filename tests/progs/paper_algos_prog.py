@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
+from repro.core.compat import make_mesh
 from repro.core import (dns_matmul, dns_matmul_pallas, generic_matmul,
                         floyd_warshall, blocked_floyd_warshall,
                         floyd_warshall_reference, make_grid_mesh)
@@ -45,7 +46,7 @@ np.testing.assert_allclose(np.asarray(blocked_floyd_warshall(D, mesh2)),
 
 # FooPar TP matmuls (algebra inside pjit)
 from repro.core.tensor_ops import foopar_matmul_row, foopar_matmul_col, dns_matmul_2d
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 x = jnp.array(rng.randn(16, 8), jnp.float32)
 w = jnp.array(rng.randn(8, 12), jnp.float32)
 ref = np.asarray(x) @ np.asarray(w)

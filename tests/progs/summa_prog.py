@@ -14,13 +14,14 @@ import jax.numpy as jnp
 import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
+from repro.core.compat import make_mesh
 from repro.core import (cannon_matmul, cannon_matmul_25d, costmodel,
                         summa_matmul, summa_matmul_pipelined)
 
 MESHES = {
-    (2, 2): jax.make_mesh((2, 2), ("x", "y"), devices=jax.devices()[:4]),
-    (2, 4): jax.make_mesh((2, 4), ("x", "y")),
-    (2, 2, 2): jax.make_mesh((2, 2, 2), ("x", "y", "z")),
+    (2, 2): make_mesh((2, 2), ("x", "y"), devices=jax.devices()[:4]),
+    (2, 4): make_mesh((2, 4), ("x", "y")),
+    (2, 2, 2): make_mesh((2, 2, 2), ("x", "y", "z")),
 }
 ALGS = {"summa": summa_matmul, "cannon": cannon_matmul,
         "summa_pipelined": summa_matmul_pipelined,

@@ -19,6 +19,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
+from repro.core.compat import make_mesh
 from repro import configs
 from repro.config import ParallelConfig, ShapeConfig, TrainConfig
 from repro.data import make_batch_iterator
@@ -52,7 +53,7 @@ def main():
     assert len(jax.devices()) == 8
     cfg = reduced(configs.get("llama3.2-3b")).replace(
         vocab=64, dtype="float32", param_dtype="float32")
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     tcfg = TrainConfig(lr=3e-3, warmup_steps=2, total_steps=20, z_loss=0.0)
 
     losses_ar, state_ar = run("all_reduce", mesh, cfg, tcfg)

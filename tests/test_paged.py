@@ -51,8 +51,8 @@ def _paged_case(seed, b, hkv, rep, hd, n_blocks, blk, pages):
     table entries.  Lengths exercise the partially-filled last page."""
     rng = np.random.RandomState(seed)
     q = rng.randn(b, hkv, rep, hd).astype(np.float32)
-    k = rng.randn(n_blocks, blk, hkv, hd).astype(np.float32)
-    v = rng.randn(n_blocks, blk, hkv, hd).astype(np.float32)
+    k = rng.randn(n_blocks, hkv, blk, hd).astype(np.float32)
+    v = rng.randn(n_blocks, hkv, blk, hd).astype(np.float32)
     perm = rng.permutation(n_blocks)
     tables = np.full((b, pages), -1, np.int32)
     lengths = np.zeros((b,), np.int32)
@@ -71,16 +71,16 @@ def _paged_case(seed, b, hkv, rep, hd, n_blocks, blk, pages):
 def _dense_oracle(q, k, v, tables, lengths):
     """Gather each chain into a dense (B, L, Hkv, hd) cache and run the
     model's own ``_sdpa`` with the valid-length mask."""
-    b = q.shape[0]
-    blk = k.shape[1]
+    b, hkv, _, hd = q.shape
+    blk = k.shape[2]
     lmax = tables.shape[1] * blk
-    kd = np.zeros((b,) + (lmax,) + k.shape[2:], np.float32)
+    kd = np.zeros((b, lmax, hkv, hd), np.float32)
     vd = np.zeros_like(kd)
     for row in range(b):
         for j, t in enumerate(np.asarray(tables[row])):
             if t >= 0:
-                kd[row, j * blk:(j + 1) * blk] = np.asarray(k)[t]
-                vd[row, j * blk:(j + 1) * blk] = np.asarray(v)[t]
+                kd[row, j * blk:(j + 1) * blk] = np.asarray(k)[t].swapaxes(0, 1)
+                vd[row, j * blk:(j + 1) * blk] = np.asarray(v)[t].swapaxes(0, 1)
     out = L._sdpa(q[:, None], jnp.asarray(kd), jnp.asarray(vd), causal=False,
                   window=None, q_offset=0, kv_len_valid=lengths)
     return out[:, 0]

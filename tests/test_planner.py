@@ -9,12 +9,11 @@ import warnings
 import jax
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro import configs
 from repro.config import ParallelConfig
 from repro.core import costmodel
-from repro.core.compat import abstract_mesh
 from repro.models import transformer as T
 from repro.models.moe import MeshCtx
 from repro.parallel import planner
@@ -130,7 +129,7 @@ def test_plan_lattice_head_is_runnable_when_nothing_fits():
 # layout rules
 # ---------------------------------------------------------------------------
 def _ctx8():
-    mesh = abstract_mesh((8, 1), ("data", "model"))
+    mesh = AbstractMesh((8, 1), ("data", "model"))
     return MeshCtx(mesh=mesh, batch_axes=("data",), model_axis="model",
                    fsdp_axes=())
 
@@ -164,7 +163,7 @@ def test_scatter_specs_noop_on_fsdp_sharded_leaves():
     from repro.launch.train import reduced
     rcfg = reduced(configs.get(ARCH))
     params = jax.eval_shape(lambda: T.init(jax.random.PRNGKey(0), rcfg))
-    mesh = abstract_mesh((8, 1), ("data", "model"))
+    mesh = AbstractMesh((8, 1), ("data", "model"))
     ctx = MeshCtx(mesh=mesh, batch_axes=("data",), model_axis="model",
                   fsdp_axes=("data",))
     sspec = scatter_specs(params, rcfg, ctx)
@@ -187,7 +186,7 @@ def test_opt_specs_scatter_layout():
 
 
 def test_sanitize_spec_reports_dropped_partitions():
-    mesh = abstract_mesh((8, 1), ("data", "model"))
+    mesh = AbstractMesh((8, 1), ("data", "model"))
     reset_dropped_partitions()
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")

@@ -55,10 +55,10 @@ def test_param_spec_rules_cover_all_archs():
     from repro.parallel.sharding import param_specs
     from repro.models.moe import MeshCtx
     from repro.models import encdec as E
-    from repro.core.compat import abstract_mesh
+    from jax.sharding import AbstractMesh
     from jax.sharding import PartitionSpec as P
 
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     for arch in configs.ARCHS:
         cfg = configs.get(arch)
         init = E.init if cfg.enc_dec else T.init
@@ -82,8 +82,8 @@ def test_param_spec_rules_cover_all_archs():
 def test_build_cell_all_40():
     """All 40 (arch × shape) cells construct abstract inputs + shardings."""
     from repro.launch.specs import build_cell
-    from repro.core.compat import abstract_mesh
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     n = 0
     for arch, shape_name, skip in configs.cells():
         n += 1
@@ -109,3 +109,30 @@ def test_train_launcher_with_fault_injection():
         cwd=__import__("os").path.join(__import__("os").path.dirname(__file__), ".."))
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
     assert "OK" in r.stdout
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR, when set, places the persistent cache and
+    the entry points set no directory of their own; otherwise the cache goes
+    to one fixed, git-ignored directory inside the checkout."""
+    from repro.launch import cache
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    prev = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "before"))
+    try:
+        cache.use_compile_cache()
+        got = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    if from_env:
+        assert got == str(tmp_path / "before")
+    else:
+        root = cache.REPO_CACHE_DIR.parent
+        assert got == str(cache.REPO_CACHE_DIR)
+        assert (root / "chip_smoke.py").exists()
+        ignored = (root / ".gitignore").read_text().split()
+        assert cache.REPO_CACHE_DIR.name + "/" in ignored
