@@ -127,6 +127,7 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(block_tables, lengths, q, k_pages, v_pages)
 
 

@@ -52,11 +52,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from repro.config import ModelConfig, ParallelConfig
 from repro.models import transformer as T
 from repro.parallel import steps as S
-from repro.serving import BlockPool
+from repro.serving import BlockPool, telemetry
 
 
 def sample_tokens(logits: jax.Array, key: jax.Array, temperature: float,
@@ -98,15 +99,14 @@ class Completion:
     arrival: int
     admitted_tick: int
     done_tick: int
-    admitted_s: float              # wall seconds from run start
-    first_token_s: float           # wall seconds from run start
-    done_s: float
+    due_s: float                   # the engine reached the arrival tick
+    admitted_s: float              # (all three: wall seconds from run start)
+    first_token_s: float
 
     @property
     def ttft_s(self) -> float:
-        """Admission → first token (prefill latency; queue wait is virtual
-        ticks, so pre-admission wall time is not a serving latency)."""
-        return self.first_token_s - self.admitted_s
+        """Due → first token: the wait for a slot or pages, then prefill."""
+        return self.first_token_s - self.due_s
 
 
 @dataclass
@@ -114,6 +114,7 @@ class _Slot:
     req: Request
     tokens: List[int] = field(default_factory=list)
     admitted_tick: int = 0
+    due_s: float = 0.0
     admitted_s: float = 0.0
     first_token_s: float = 0.0
     state: str = "decode"          # "prefill" while chunked prefill runs
@@ -335,11 +336,12 @@ class Scheduler:
             return
         st.state, st.cursor = "prefill", 0
 
-    def _prefill_chunk_tick(self, slot: int, st: _Slot) -> Optional[int]:
+    def _prefill_chunk_tick(self, slot: int, st: _Slot) -> Optional[jax.Array]:
         """Consume ONE ``chunk``-token slice of ``slot``'s prompt (the
         admission-stall bound: in-flight decodes wait for at most this one
         fixed-shape call per prefilling slot per tick).  Returns the first
-        generated token when the prompt completes, else None."""
+        generated token, still on the device, when the prompt completes,
+        else None; ``run`` picks it up after the tick's last chunk."""
         prompt = np.asarray(st.req.prompt, np.int32)
         lp = int(prompt.shape[0])
         lo = st.cursor
@@ -355,26 +357,34 @@ class Scheduler:
         if st.cursor < lp:
             return None
         st.state = "decode"
+        self._pos[slot] = lp
         if self.sampling:
-            first = int(sample_tokens(logits, self._next_key(),
-                                      self.temperature, self.top_p)[0])
-        else:
-            first = int(jnp.argmax(logits, axis=-1)[0])
-        self._tok[slot], self._pos[slot] = first, lp
-        return first
+            return sample_tokens(logits, self._next_key(), self.temperature,
+                                 self.top_p)[0]
+        return jnp.argmax(logits, axis=-1)[0]
 
     # ------------------------------------------------------------------
+    def _pages(self) -> tuple:
+        if not self.paged:
+            return 0, 0
+        return self.pool.reserved_blocks, self.pool.live_blocks
+
+    @telemetry.gc_spans()
     def run(self, requests: Sequence[Request] = (), *,
             on_token: Optional[Callable[[int, int], None]] = None) -> dict:
         """Serve ``requests`` (plus anything already ``submit``ted) to
         completion.  Tokens stream per request through ``on_token(rid,
         token)`` (one host sync per engine tick).  Returns completions plus
         aggregate wall-time / throughput metrics (and the block pool's
-        occupancy/fragmentation report in paged mode)."""
+        occupancy/fragmentation report in paged mode).  Each tick is tiled
+        by ``serve.*`` profiler spans and logged in ``serving.telemetry``."""
         for req in requests:
             self.submit(req)
         pending = deque(sorted(self._queue, key=lambda r: (r.arrival, r.rid)))
         self._queue = []
+        arrivals = list(pending)
+        reached = 0                  # arrivals whose tick the engine reached
+        due: Dict[int, float] = {}
         active: Dict[int, _Slot] = {}
         free = list(range(self.slots - 1, -1, -1))
         done: Dict[int, Completion] = {}
@@ -393,8 +403,8 @@ class Scheduler:
             done[st.req.rid] = Completion(
                 rid=st.req.rid, tokens=st.tokens, arrival=st.req.arrival,
                 admitted_tick=st.admitted_tick, done_tick=tick,
-                admitted_s=st.admitted_s, first_token_s=st.first_token_s,
-                done_s=time.perf_counter() - t0)
+                due_s=st.due_s, admitted_s=st.admitted_s,
+                first_token_s=st.first_token_s)
 
         def emit(slot: int, tok: int) -> None:
             nonlocal generated
@@ -406,73 +416,113 @@ class Scheduler:
             if on_token is not None:
                 on_token(st.req.rid, tok)
 
+        def log_tick(t: float, at: int, chunks: int, chunk_tokens: int,
+                     pages: tuple) -> None:
+            telemetry.LOG.append(telemetry.Tick(t, at, chunks, chunk_tokens,
+                                                *pages))
+
         while pending or active:
-            while pending and free and pending[0].arrival <= tick:
-                if self.paged and not self.pool.can_admit(
-                        len(pending[0].prompt) + pending[0].gen):
-                    break          # FIFO head waits for pages to free up
-                req = pending.popleft()
-                slot = free.pop()
-                st = _Slot(req=req, admitted_tick=tick,
-                           admitted_s=time.perf_counter() - t0)
-                active[slot] = st
-                if self.paged:
-                    self._admit_paged(req, slot, st)
-                else:
-                    first = self._admit(req, slot)
-                    if first is not None:
-                        emit(slot, first)
-                        if len(st.tokens) >= req.gen:
-                            finish(slot)
+            now = time.perf_counter() - t0
+            while reached < len(arrivals) and arrivals[reached].arrival <= tick:
+                due[arrivals[reached].rid] = now
+                reached += 1
+            chunks = chunk_tokens = 0
+            queued = len(pending)
+            with TraceAnnotation("serve.admit", tick=tick):
+                while pending and free and pending[0].arrival <= tick:
+                    if self.paged and not self.pool.can_admit(
+                            len(pending[0].prompt) + pending[0].gen):
+                        break      # FIFO head waits for pages to free up
+                    req = pending.popleft()
+                    slot = free.pop()
+                    st = _Slot(req=req, admitted_tick=tick,
+                               due_s=due.pop(req.rid),
+                               admitted_s=time.perf_counter() - t0)
+                    active[slot] = st
+                    if self.paged:
+                        self._admit_paged(req, slot, st)
+                    else:
+                        first = self._admit(req, slot)
+                        if first is not None:
+                            emit(slot, first)
+                            if len(st.tokens) >= req.gen:
+                                finish(slot)
             if self.paged:
                 # chunked prefill: one fixed-shape chunk per prefilling slot
                 # per tick, interleaved with the decode tick below
-                for slot in list(active):
-                    st = active[slot]
-                    if st.state != "prefill":
-                        continue
-                    first = self._prefill_chunk_tick(slot, st)
-                    if first is not None:
-                        emit(slot, first)
-                        if len(st.tokens) >= st.req.gen:
-                            finish(slot)
+                firsts = []        # (slot, first token on the device)
+                with TraceAnnotation("serve.prefill", tick=tick):
+                    for slot in list(active):
+                        st = active[slot]
+                        if st.state != "prefill":
+                            continue
+                        before = st.cursor
+                        first = self._prefill_chunk_tick(slot, st)
+                        chunks += 1
+                        chunk_tokens += st.cursor - before
+                        if first is not None:
+                            firsts.append((slot, first))
+                if firsts:
+                    # one wait for all of the tick's chunks, kept apart
+                    # from the host's own work as serve.sync is
+                    with TraceAnnotation("serve.pick", tick=tick):
+                        firsts = [(slot, int(f)) for slot, f in firsts]
+                    with TraceAnnotation("serve.emit", tick=tick):
+                        for slot, first in firsts:
+                            self._tok[slot] = first
+                            emit(slot, first)
+                            st = active[slot]
+                            if len(st.tokens) >= st.req.gen:
+                                finish(slot)
             decoding = [s for s, st in active.items() if st.state == "decode"]
             if not decoding:
+                if chunks or len(pending) < queued:
+                    log_tick(time.perf_counter(), tick, chunks, chunk_tokens,
+                             self._pages())
                 if active:
                     tick += 1      # prefill-only tick still advances time
                 else:
                     # nothing resident: fast-forward the virtual clock
                     tick = pending[0].arrival if pending else tick + 1
                 continue
-            if self.paged:
-                # alloc-on-write: this tick's token lands at pos, so each
-                # decoding row's chain must cover pos+1 tokens (reserved at
-                # admission — ensure can't fail); refresh the device tables
-                for slot in decoding:
-                    st = active[slot]
-                    self.pool.ensure(st.req.rid, int(self._pos[slot]) + 1)
-                    self._tables[slot] = self.pool.table(st.req.rid,
-                                                         self.n_pages)
-                args = (jnp.asarray(self._tok), self.cache,
-                        jnp.asarray(self._pos), jnp.asarray(self._tables))
-            else:
-                args = (jnp.asarray(self._tok), self.cache,
-                        jnp.asarray(self._pos))
-            if self.sampling:
-                nxt, self.cache = self._decode(self.params, *args,
-                                               self._next_key())
-            else:
-                nxt, self.cache = self._decode(self.params, *args)
-            nxt = np.asarray(nxt)               # host sync = the stream point
+            with TraceAnnotation("serve.prepare", tick=tick):
+                if self.paged:
+                    # alloc-on-write: this tick's token lands at pos, so each
+                    # decoding row's chain must cover pos+1 tokens (reserved
+                    # at admission — ensure can't fail); refresh the tables
+                    for slot in decoding:
+                        st = active[slot]
+                        self.pool.ensure(st.req.rid, int(self._pos[slot]) + 1)
+                        self._tables[slot] = self.pool.table(st.req.rid,
+                                                             self.n_pages)
+                    args = (jnp.asarray(self._tok), self.cache,
+                            jnp.asarray(self._pos), jnp.asarray(self._tables))
+                else:
+                    args = (jnp.asarray(self._tok), self.cache,
+                            jnp.asarray(self._pos))
+            with TraceAnnotation("serve.dispatch", tick=tick):
+                if self.sampling:
+                    nxt, self.cache = self._decode(self.params, *args,
+                                                   self._next_key())
+                else:
+                    nxt, self.cache = self._decode(self.params, *args)
+            with TraceAnnotation("serve.sync", tick=tick):
+                nxt = np.asarray(nxt)       # host sync = the stream point
+            t_sync, pages = time.perf_counter(), self._pages()
             tick += 1
-            for slot in decoding:
-                if slot not in active:
-                    continue
-                self._pos[slot] += 1
-                self._tok[slot] = nxt[slot]
-                emit(slot, int(nxt[slot]))
-                if len(active[slot].tokens) >= active[slot].req.gen:
-                    finish(slot)
+            try:
+                with TraceAnnotation("serve.emit", tick=tick - 1):
+                    for slot in decoding:
+                        if slot not in active:
+                            continue
+                        self._pos[slot] += 1
+                        self._tok[slot] = nxt[slot]
+                        emit(slot, int(nxt[slot]))
+                        if len(active[slot].tokens) >= active[slot].req.gen:
+                            finish(slot)
+            finally:
+                # logged even when a token callback ends the run
+                log_tick(t_sync, tick - 1, chunks, chunk_tokens, pages)
         jax.block_until_ready(self.cache)
         wall = time.perf_counter() - t0
         out = {

@@ -138,9 +138,11 @@ def main():
     print(f"served {args.requests} requests [{mode}, fused_prefill="
           f"{sched.fused}]: {out['generated']} toks in {out['wall_s']:.2f}s "
           f"({out['tok_s']:.1f} tok/s, {out['ticks']} ticks)")
-    print(f"ttft (admission->first token) p50/p99: "
-          f"{ttft[len(ttft) // 2] * 1e3:.1f}/"
-          f"{ttft[int(len(ttft) * 0.99)] * 1e3:.1f} ms")
+    # nearest-rank percentiles; a p99 of fewer than 100 samples is the max
+    pcts = (50, 90, 99) if len(ttft) >= 100 else (50, 90)
+    ms = [ttft[-(-len(ttft) * p // 100) - 1] * 1e3 for p in pcts]
+    print(f"ttft (due->first token) {'/'.join(f'p{p}' for p in pcts)}: "
+          f"{'/'.join(f'{v:.1f}' for v in ms)} ms")
     if args.paged:
         rep = out["pool"]
         print(f"pool: {rep['n_blocks']} blocks x {rep['block']} toks, peak "

@@ -265,12 +265,14 @@ def make_decode_step(cfg: ModelConfig, pcfg: ParallelConfig,
         return jnp.argmax(logit, axis=-1).astype(jnp.int32), new_cache
 
     def decode_paged(params, token, cache, pos, block_tables):
-        logit, new_cache = T.decode_step(params, token, cache, pos, cfg,
-                                         ctx=ctx, unroll=pcfg.scan_unroll,
-                                         block_tables=block_tables)
-        if return_logits:
-            return logit.astype(jnp.float32), new_cache
-        return jnp.argmax(logit, axis=-1).astype(jnp.int32), new_cache
+        # a stable name for the step in device traces (op metadata)
+        with jax.named_scope("decode_paged"):
+            logit, new_cache = T.decode_step(params, token, cache, pos, cfg,
+                                             ctx=ctx, unroll=pcfg.scan_unroll,
+                                             block_tables=block_tables)
+            if return_logits:
+                return logit.astype(jnp.float32), new_cache
+            return jnp.argmax(logit, axis=-1).astype(jnp.int32), new_cache
 
     return decode_paged if paged else decode
 
@@ -288,8 +290,9 @@ def make_chunk_prefill_step(cfg: ModelConfig, pcfg: ParallelConfig,
         raise NotImplementedError("chunked prefill is decoder-only")
 
     def chunk_prefill(params, tokens, cache, pos0, block_tables, length):
-        return T.prefill_paged(params, tokens, cache, cfg, pos0=pos0,
-                               block_tables=block_tables, length=length,
-                               ctx=ctx, unroll=pcfg.scan_unroll)
+        with jax.named_scope("chunk_prefill"):
+            return T.prefill_paged(params, tokens, cache, cfg, pos0=pos0,
+                                   block_tables=block_tables, length=length,
+                                   ctx=ctx, unroll=pcfg.scan_unroll)
 
     return chunk_prefill

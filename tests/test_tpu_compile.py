@@ -11,6 +11,8 @@ this file.  The kernels are called directly (not through the auto-dispatch
 wrappers, which see the CPU backend here and would pick interpret mode or
 the jnp reference).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -60,6 +62,13 @@ def test_paged_attention_compiles(one_chip, arch):
                          ((b, hkv, rep, hd), jnp.bfloat16), arena, arena,
                          ((b, pages), jnp.int32), ((b,), jnp.int32))
     assert "tpu_custom_call" in text
+    # device-trace readers find the kernel by its name and its first
+    # operand, the (slots, pages) block table
+    call = re.search(r"%paged_attention\.\d+ = .*?custom-call\((%[\w.\-]+)",
+                     text)
+    assert call, "no custom call named paged_attention"
+    table = re.search(re.escape(call.group(1)) + r" = (s32\[\d+,\d+\])", text)
+    assert table and table.group(1) == f"s32[{b},{pages}]"
 
 
 def test_matmul_acc_compiles(one_chip):
