@@ -19,6 +19,9 @@ One chip (no ``--chips``), all in this one process:
                 same weights;
   * kernel   -- ``paged_attention`` (Pallas) against ``ref.paged_attention``
                 at the model's decode shapes, within ``KERNEL_TOL``;
+  * kernel_cells -- the same check at the benchmark cells' decode shapes
+                (48 slots, 214-page tables, 4,096 pages) for both head
+                layouts, a parked row among them;
   * oracle   -- the paged-vs-end-aligned token-identity oracle of
                 ``tests/test_paged.py`` at reduced width (f32, highest
                 matmul precision);
@@ -257,6 +260,55 @@ def phase_kernel(cfg, seed: int):
                  f"(tol {KERNEL_TOL})")
 
 
+def phase_kernel_cells(seed: int):
+    """The Pallas kernel at the benchmark cells' decode shapes -- 48 slots,
+    a 214-page table, a 4,096-page arena -- for both head layouts
+    (chatglm3-6b's 2 KV heads of 16 queries, chameleon-34b's 8 of 8), with
+    a full-table row, short rows, a parked row (table all -1, nonzero
+    length), against ``ref.paged_attention`` in float32."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro import configs
+    from repro.kernels import ops, ref
+    slots, pages, n_blocks = 48, 214, 4096
+    rng = np.random.RandomState(seed)
+    lengths = np.ones((slots,), np.int32)            # parked rows: length 1
+    lengths[:28] = [pages * BLOCK, 500, 1, BLOCK + 1] + list(
+        rng.randint(402, 2561, 24))
+    tables = np.full((slots, pages), -1, np.int32)
+    perm, used = rng.permutation(n_blocks), 0
+    for row in [0] + list(range(2, 28)):             # row 1 stays parked
+        chain = -(-int(lengths[row]) // BLOCK)
+        tables[row, :chain] = perm[used:used + chain]
+        used += chain
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lengths)
+    notes = []
+    for arch in ("chatglm3-6b", "chameleon-34b"):
+        cfg = configs.get(arch)
+        hkv, rep, hd = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.hd
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        q = jax.random.normal(ks[0], (slots, hkv, rep, hd), jnp.bfloat16)
+        kp = jax.random.normal(ks[1], (n_blocks, hkv, BLOCK, hd), jnp.bfloat16)
+        vp = jax.random.normal(ks[2], (n_blocks, hkv, BLOCK, hd), jnp.bfloat16)
+        got = jax.jit(ops.paged_attention)(q, kp, vp, tables, lengths)
+        with jax.default_matmul_precision("highest"):
+            f32 = lambda x: x.astype(jnp.float32)
+            truth = jax.jit(ref.paged_attention)(f32(q), f32(kp), f32(vp),
+                                                 tables, lengths)
+        live = np.asarray(tables[:, 0] >= 0)
+        err = float(jnp.max(jnp.abs(f32(got) - truth)[live]))
+        check(bool(jnp.all(jnp.isfinite(got))),
+              f"{arch}: kernel output is not finite at the cells' shapes")
+        check(bool(jnp.all(got[~live] == 0)),
+              f"{arch}: a parked row's output is not 0")
+        check(err <= KERNEL_TOL, f"{arch}: kernel vs f32 reference at the "
+              f"cells' shapes: max abs err {err} > {KERNEL_TOL}")
+        notes.append(f"{arch} Hkv={hkv} rep={rep}: max abs err {err}")
+    return got, (f"B={slots} pages={pages} arena={n_blocks}: "
+                 + "; ".join(notes) + f" (tol {KERNEL_TOL})")
+
+
 def phase_oracle():
     import jax
     sys.path.insert(0, os.path.join(ROOT, "tests"))
@@ -306,6 +358,7 @@ def one_chip(seed: int) -> None:
     run_phase(jax, "aligned", phase_aligned, cfg, params, aligned, comps)
     del params
     run_phase(jax, "kernel", phase_kernel, cfg, seed)
+    run_phase(jax, "kernel_cells", phase_kernel_cells, seed)
     run_phase(jax, "oracle", phase_oracle)
     run_phase(jax, "train", phase_train, configs.get(ARCH), seed)
 

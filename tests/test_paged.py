@@ -15,12 +15,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import configs
 from repro.config import ParallelConfig
 from repro.core import costmodel
 from repro.kernels import ref
-from repro.kernels.paged_attention import paged_attention, paged_attention_pallas
+from repro.kernels.paged_attention import (_pages_per_group, paged_attention,
+                                           paged_attention_pallas)
 from repro.launch.scheduler import Request, Scheduler
 from repro.launch.train import reduced
 from repro.models import layers as L
@@ -45,21 +47,26 @@ PCFG = ParallelConfig(remat="none", fsdp_params=False)
 # ---------------------------------------------------------------------------
 # Kernel oracle: page view ≡ dense attention over the gathered sequence
 # ---------------------------------------------------------------------------
-def _paged_case(seed, b, hkv, rep, hd, n_blocks, blk, pages):
+def _paged_case(seed, b, hkv, rep, hd, n_blocks, blk, pages, lengths=None):
     """Random arena + per-request chains with garbage in unused blocks and
     beyond each row's valid length (masking must hide both), plus -1 tail
-    table entries.  Lengths exercise the partially-filled last page."""
+    table entries.  Lengths exercise the partially-filled last page; given
+    ``lengths`` replace the drawn ones."""
     rng = np.random.RandomState(seed)
     q = rng.randn(b, hkv, rep, hd).astype(np.float32)
     k = rng.randn(n_blocks, hkv, blk, hd).astype(np.float32)
     v = rng.randn(n_blocks, hkv, blk, hd).astype(np.float32)
     perm = rng.permutation(n_blocks)
     tables = np.full((b, pages), -1, np.int32)
-    lengths = np.zeros((b,), np.int32)
+    drawn = lengths is None
+    lengths = np.zeros((b,), np.int32) if drawn else np.asarray(lengths,
+                                                                 np.int32)
     used = 0
     for row in range(b):
         # row 0 fills every page exactly; later rows end mid-page
-        lengths[row] = pages * blk if row == 0 else rng.randint(1, pages * blk)
+        if drawn:
+            lengths[row] = (pages * blk if row == 0
+                            else rng.randint(1, pages * blk))
         chain = -(-int(lengths[row]) // blk)
         tables[row, :chain] = perm[used:used + chain]
         used += chain
@@ -95,16 +102,64 @@ def test_paged_ref_matches_dense_sdpa(rep):
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("rep", [1, 4])
-def test_paged_pallas_matches_ref(rep):
-    case = _paged_case(1, b=2, hkv=2, rep=rep, hd=16, n_blocks=10, blk=4,
-                       pages=4)
-    got = paged_attention_pallas(*case, interpret=True)
-    want = ref.paged_attention(*case)
+def _walked(tables, lengths, blk):
+    """What the kernel's walk covers of each row: its length, cut at its
+    first -1 table entry (a parked row, table all -1, covers nothing)."""
+    tables, lengths = np.asarray(tables), np.asarray(lengths)
+    dead = np.concatenate([tables < 0, np.ones((len(tables), 1), bool)], 1)
+    return np.minimum(lengths, dead.argmax(1) * blk).astype(np.int32)
+
+
+# (hkv, rep, hd, blk, pages, lengths, parked rows, (row, entry) holes): the
+# first two are the original MHA and 4-wide GQA cases, one page group; the
+# rest walk several groups of a table wider than, and no multiple of, G
+WALKS = {
+    "1": (2, 1, 16, 4, 4, None, (), ()),
+    "4": (2, 4, 16, 4, 4, None, (), ()),
+    # chatglm3-6b's layout, 2 KV heads of 16 queries: G = 32 pages; rows:
+    # the full table, one ending mid-page in its second group, one just
+    # past a page, and a parked row with a nonzero length
+    "kv2-rep16": (2, 16, 32, 16, 40, (640, 600, 17, 50), (3,), ()),
+    # chameleon-34b's layout, 8 KV heads of 8 queries, at a width whose
+    # pages (64 KiB in f32) cut G to 16 to fit the VMEM budget; mid-page
+    # and mid-group ends, a one-token row
+    "kv8-rep8": (8, 8, 128, 16, 40, (640, 410, 1, 50), (3,), ()),
+    # a -1 entry inside a row's length ends its walk there
+    "hole": (8, 8, 32, 16, 40, (640, 600, 130, 1), (), ((1, 9), (0, 39))),
+}
+
+
+@pytest.mark.parametrize("walk, mode", [
+    pytest.param(w, m, id=w + ("-tpu" if m else ""))
+    for m in (None, "tpu") for w in WALKS])
+def test_paged_pallas_matches_ref(walk, mode):
+    """Against the reference over what the walk covers, under both interpret
+    modes: discharged to XLA, and the TPU interpreter with its uninitialised
+    memory (NaN) and DMA semaphores."""
+    hkv, rep, hd, blk, pages, lengths, parked, holes = WALKS[walk]
+    b = 2 if lengths is None else len(lengths)
+    q, k, v, tables, lengths = _paged_case(
+        1, b=b, hkv=hkv, rep=rep, hd=hd, n_blocks=10 if b == 2 else 96,
+        blk=blk, pages=pages, lengths=lengths)
+    for row in parked:
+        tables = tables.at[row].set(-1)
+    for row, entry in holes:
+        tables = tables.at[row, entry].set(-1)
+    walked = _walked(tables, lengths, blk)
+    if b > 2:
+        group = _pages_per_group(hkv * blk * hd * 4, blk, pages)
+        assert group < pages and pages % group
+        assert walked.max() > group * blk         # several groups walked
+    got = paged_attention_pallas(
+        q, k, v, tables, lengths,
+        interpret=pltpu.InterpretParams() if mode else True)
+    want = ref.paged_attention(q, k, v, tables, jnp.asarray(walked))
+    want = jnp.where(jnp.asarray(walked > 0)[:, None, None, None], want, 0.0)
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
-    # the auto-dispatch entry must agree too (ref backend off-TPU)
-    auto = paged_attention(*case)
-    np.testing.assert_allclose(auto, want, atol=1e-6, rtol=1e-6)
+    if not parked and not holes:
+        # the auto-dispatch entry must agree too (ref backend off-TPU)
+        auto = paged_attention(q, k, v, tables, lengths)
+        np.testing.assert_allclose(auto, want, atol=1e-6, rtol=1e-6)
 
 
 def test_paged_pallas_dead_rows_are_finite():

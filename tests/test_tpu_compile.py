@@ -51,11 +51,20 @@ def _compile_text(fn, sharding, *shapes) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("arch", ["chatglm3-6b", "llama3.2-3b"])
-def test_paged_attention_compiles(one_chip, arch):
-    """Decode shapes of the arch: 8 slots, 16-token pages, 128-page tables."""
+@pytest.mark.parametrize("arch, b, pages, n_blocks", [
+    pytest.param("chatglm3-6b", 8, 128, 1024, id="chatglm3-6b"),
+    pytest.param("llama3.2-3b", 8, 128, 1024, id="llama3.2-3b"),
+    # the benchmark cells' decode shapes: 48 slots, 214-page tables, a
+    # 4,096-page arena, for both head layouts (2 KV heads of 16 queries,
+    # 8 of 8)
+    pytest.param("chatglm3-6b", 48, 214, 4096, id="chatglm3-6b.chat"),
+    pytest.param("chameleon-34b", 48, 214, 4096, id="chameleon-34b.chat"),
+])
+def test_paged_attention_compiles(one_chip, arch, b, pages, n_blocks):
+    """Decode shapes of the arch: ``b`` slots, 16-token pages, tables of
+    ``pages`` entries over an ``n_blocks``-page arena."""
     cfg = configs.get(arch)
-    b, blk, pages, n_blocks = 8, 16, 128, 1024
+    blk = 16
     hkv, rep, hd = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.hd
     arena = ((n_blocks, hkv, blk, hd), jnp.bfloat16)
     text = _compile_text(paged_attention_pallas, one_chip,
