@@ -22,6 +22,25 @@ class MoEConfig:
     n_shared_experts: int = 0      # dense experts always active (Kimi-style)
     capacity_factor: float = 1.25
     router_dtype: str = "float32"
+    # routing: scores over all n_experts (softmax, or sigmoid as in
+    # DeepSeek-V3), the top_k picked and divided by their sum, times
+    # routed_scale
+    scoring: Literal["softmax", "sigmoid"] = "softmax"
+    routed_scale: float = 1.0
+    # this chip's share of an expert-parallel deployment: experts
+    # first_held .. first_held + n_held - 1 (None: all of them); the router
+    # keeps its n_experts outputs
+    n_held: Optional[int] = None
+    first_held: int = 0
+
+    def __post_init__(self):
+        if self.first_held < 0 or self.first_held + self.held > self.n_experts:
+            raise ValueError(f"held experts {self.first_held} + {self.held} "
+                             f"exceed the router's {self.n_experts}")
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.n_held is None else self.n_held
 
 
 @dataclass(frozen=True)
@@ -57,11 +76,19 @@ class ModelConfig:
     head_dim: Optional[int] = None          # default d_model // n_heads
     # block structure: period of layer kinds, tiled to n_layers
     block_pattern: Tuple[str, ...] = ("attn",)   # attn|mamba2|mamba2_attn|mlstm|slstm
+    first_k_dense: int = 0                   # leading layers of kind "attn" before the pattern
     # attention details
     rope_theta: float = 10000.0
     rope_fraction: float = 1.0               # chatglm 2d-RoPE = 0.5
     window: Optional[int] = None             # sliding-window attention
+    # per layer "sliding_attention" (``window``) or "full_attention"; None:
+    # every layer as ``window`` says
+    layer_types: Optional[Tuple[str, ...]] = None
+    full_rope: bool = True                   # False: no rotary on the full layers of a hybrid (NoPE)
     qk_norm: bool = False                    # chameleon
+    # norms on the attention and MLP outputs before each residual add and
+    # none on their inputs (EXAONE 4.0's QK-Reorder-LN)
+    post_norm: bool = False
     parallel_block: bool = False             # command-r style attn ∥ mlp
     # norms / act
     norm: Literal["rmsnorm", "layernorm"] = "rmsnorm"
@@ -81,19 +108,70 @@ class ModelConfig:
     sub_quadratic: bool = False              # eligible for long_500k
     notes: str = ""
 
+    def __post_init__(self):
+        # from JSON, nested groups arrive as dicts and tuples as lists
+        for name, kind in (("moe", MoEConfig), ("ssm", SSMConfig),
+                           ("xlstm", XLSTMConfig)):
+            if isinstance(getattr(self, name), dict):
+                object.__setattr__(self, name, kind(**getattr(self, name)))
+        for name in ("block_pattern", "layer_types"):
+            if isinstance(getattr(self, name), list):
+                object.__setattr__(self, name, tuple(getattr(self, name)))
+        if self.layer_types is not None and (
+                len(self.layer_types) != self.n_layers
+                or not set(self.layer_types) <= {"sliding_attention",
+                                                 "full_attention"}):
+            raise ValueError(f"{self.name}: layer_types must give "
+                             f"'sliding_attention' or 'full_attention' for "
+                             f"each of the {self.n_layers} layers")
+
     @property
     def hd(self) -> int:
         return self.head_dim if self.head_dim is not None else self.d_model // self.n_heads
 
+    def _layer(self, i: int) -> Tuple[str, Optional[int]]:
+        """Layer ``i``'s block kind and attention window."""
+        k = self.first_k_dense
+        kind = "attn" if i < k else \
+            self.block_pattern[(i - k) % len(self.block_pattern)]
+        full = self.layer_types is not None and \
+            self.layer_types[i] == "full_attention"
+        return kind, None if full else self.window
+
+    def _period_len(self) -> int:
+        """The layers the scan's body holds: the shortest run of layers
+        that repeats to ``n_layers`` (a multiple of the pattern's length
+        unless leading dense layers break it; the whole stack at worst)."""
+        n = self.n_layers
+        step = 1 if self.first_k_dense else len(self.block_pattern)
+        spec = [self._layer(i) for i in range(n)]
+        for p in range(step, n + 1, step):
+            if n % p == 0 and all(spec[i] == spec[i % p] for i in range(n)):
+                return p
+        raise AssertionError((self.name, self.n_layers, self.block_pattern))
+
     @property
     def period(self) -> Tuple[str, ...]:
-        return self.block_pattern
+        return tuple(self._layer(i)[0] for i in range(self._period_len()))
+
+    @property
+    def period_cfgs(self) -> Tuple["ModelConfig", ...]:
+        """Per position of the period, the config its attention runs with:
+        the layer's own window, and no rotary on a full layer of a hybrid
+        without full-layer rotary."""
+        out = []
+        for i in range(self._period_len()):
+            win = self._layer(i)[1]
+            if win == self.window:
+                out.append(self)
+            else:
+                out.append(self.replace(window=win, rope_fraction=(
+                    self.rope_fraction if self.full_rope else 0.0)))
+        return tuple(out)
 
     @property
     def n_periods(self) -> int:
-        assert self.n_layers % len(self.block_pattern) == 0, \
-            (self.name, self.n_layers, self.block_pattern)
-        return self.n_layers // len(self.block_pattern)
+        return self.n_layers // self._period_len()
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -110,7 +188,7 @@ class ModelConfig:
         per_kind["attn"] = attn + mlp_mult * d * self.d_ff + 2 * d
         if self.moe:
             e = self.moe
-            experts = e.n_experts * mlp_mult * d * e.d_ff_expert
+            experts = e.held * mlp_mult * d * e.d_ff_expert
             shared = e.n_shared_experts * mlp_mult * d * e.d_ff_expert
             router = d * e.n_experts
             per_kind["attn_moe"] = attn + experts + shared + router + 2 * d
@@ -128,7 +206,7 @@ class ModelConfig:
             per_kind["mlstm"] = d * 2 * d_in + 3 * d_in * d_in // 1 + d_in * d + 2 * d
             per_kind["slstm"] = 4 * 2 * d * d + d * d + 2 * d
         total = counts["embed"] + counts["unembed"]
-        for kind in self.block_pattern:
+        for kind in self.period:
             base = kind if kind in per_kind else "attn"
             total += per_kind[base] * self.n_periods
         if "mamba2_attn" in self.block_pattern:
@@ -138,8 +216,8 @@ class ModelConfig:
         active = total
         if self.moe:
             e = self.moe
-            dead = (e.n_experts - e.top_k) * mlp_mult * d * e.d_ff_expert
-            active = total - dead * self.block_pattern.count("attn_moe") * self.n_periods
+            dead = max(e.held - e.top_k, 0) * mlp_mult * d * e.d_ff_expert
+            active = total - dead * self.period.count("attn_moe") * self.n_periods
         counts["active"] = active
         return counts
 

@@ -12,11 +12,13 @@ grid is one program per request (slot) with the KV heads folded in: each
 page is one DMA per arena, never ``Hkv`` smaller ones.
 
 Inside a program a ``fori_loop`` walks the row's *live* pages only, in
-groups of ``G`` pages: the walk ends at the row's length or at its first
-unallocated (-1) table entry, whichever comes first, so a parked row (table
-all -1) walks nothing and writes zeros.  Each group's pages are copied into
-a VMEM buffer of ``G`` pages with manual async copies, double-buffered:
-group ``i + 1`` is in flight while group ``i`` is computed.  A live page
+groups of ``G`` pages: the walk starts at the page of the row's first key
+position (0, or a sliding window's start) and ends at the row's length or
+at its first unallocated (-1) table entry, whichever comes first, so a
+parked row (table all -1) walks nothing and writes zeros.  Each group's
+pages are copied into a VMEM buffer of ``G`` pages with manual async
+copies, double-buffered: group ``i + 1`` is in flight while group ``i`` is
+computed.  A live page
 costs its two DMAs (K and V) and its share of one loop step; a dead table
 entry costs nothing but the scalar test that ends the walk.  ``G`` follows
 from the shapes (``_pages_per_group``), not from a knob.
@@ -25,7 +27,8 @@ Per group and KV head, the ``rep`` grouped query heads take f32 scores
 against the group's ``G * block`` keys; the online-softmax statistics
 (m, l) and the f32 output accumulator live in VMEM scratch across groups.
 Key positions at or past the walk's end, which includes the tail of the
-partially-filled last page, are masked per position.
+partially-filled last page, and below the row's first key position are
+masked per position.
 
 ``paged_attention`` is the public entry: on TPU it lowers the kernel (a
 lowering failure surfaces), elsewhere it runs the pure-jnp reference in
@@ -64,7 +67,7 @@ def _pages_per_group(page_bytes: int, block: int, pages: int) -> int:
     return max(1, min(g, pages))
 
 
-def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+def _paged_attn_kernel(tbl_ref, len_ref, start_ref, q_ref, k_hbm, v_hbm, o_ref,
                        k_buf, v_buf, sems, m_ref, l_ref, acc_ref, *,
                        pages: int, group: int, block: int, scale: float):
     b = pl.program_id(0)
@@ -81,18 +84,20 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # the walk: the row's pages up to its length or its first -1 entry
-    length = len_ref[b]
+    # the walk: the row's pages from its first key's up to its length or
+    # its first -1 entry
+    length, start = len_ref[b], start_ref[b]
     want = jnp.minimum(pl.cdiv(length, block), pages)
     live = jax.lax.while_loop(
         lambda j: (j < want) & (tbl_ref[b, jnp.minimum(j, pages - 1)] >= 0),
         lambda j: j + 1, 0)
     limit = jnp.minimum(length, live * block)
-    n_groups = pl.cdiv(live, group)
+    first = jnp.minimum(start // block, live)
+    n_groups = pl.cdiv(live - first, group)
 
     def copies(g, slot, fn):
         for i in range(group):
-            j = g * group + i
+            j = first + g * group + i
 
             @pl.when(j < live)
             def _page():
@@ -116,9 +121,9 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
             copies(g + 1, 1 - slot, lambda c: c.start())
 
         copies(g, slot, lambda c: c.wait())
-        kpos = g * span + jax.lax.broadcasted_iota(
-            jnp.int32, (q[0].shape[0], span), 1)
-        valid = kpos < limit
+        kpos = (first * block + g * span + jax.lax.broadcasted_iota(
+            jnp.int32, (q[0].shape[0], span), 1))
+        valid = (kpos < limit) & (kpos >= start)
         for h in range(hkv):
             k = k_buf[slot, :, h].reshape(span, -1).astype(jnp.float32)
             v = v_buf[slot, :, h].reshape(span, -1).astype(jnp.float32)
@@ -144,12 +149,16 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, block_tables: jax.Array,
-                           lengths: jax.Array, *, scale: float | None = None,
+                           lengths: jax.Array, starts: jax.Array | None = None,
+                           *, scale: float | None = None,
                            interpret: bool = False) -> jax.Array:
     """Layouts as ``ref.paged_attention``: q (B, Hkv, rep, hd); arenas
     (N, Hkv, block, hd); block_tables (B, P) int32 (-1 = unallocated);
-    lengths (B,) int32 valid tokens."""
+    lengths (B,) int32 valid tokens; starts (B,) int32 first key positions
+    (None: 0)."""
     b, hkv, rep, hd = q.shape
+    if starts is None:
+        starts = jnp.zeros_like(lengths)
     n, hkv2, blk, hd2 = k_pages.shape
     assert (hkv, hd) == (hkv2, hd2), (q.shape, k_pages.shape)
     pages = block_tables.shape[1]
@@ -161,15 +170,15 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
                                block=blk, scale=scale)
     buf = pltpu.VMEM((2, group, hkv, blk, hd), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,           # (block_tables, lengths)
+        num_scalar_prefetch=3,           # (block_tables, lengths, starts)
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, hkv, rep, hd), lambda bb, tbl, lens: (bb, 0, 0, 0)),
+            pl.BlockSpec((1, hkv, rep, hd), lambda bb, *_: (bb, 0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),      # arenas stay in HBM
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, hkv, rep, hd),
-                               lambda bb, tbl, lens: (bb, 0, 0, 0)),
+                               lambda bb, *_: (bb, 0, 0, 0)),
         scratch_shapes=[
             buf, buf,
             pltpu.SemaphoreType.DMA((2, 2)),        # (K | V, buffer)
@@ -187,14 +196,16 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
-    )(block_tables, lengths, q, k_pages, v_pages)
+    )(block_tables, lengths, starts, q, k_pages, v_pages)
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    block_tables: jax.Array, lengths: jax.Array) -> jax.Array:
+                    block_tables: jax.Array, lengths: jax.Array,
+                    starts: jax.Array | None = None) -> jax.Array:
     """Paged decode attention, the model decode path's entry: the Pallas
     kernel on TPU, the jnp reference elsewhere."""
     if jax.default_backend() == "tpu":
         return paged_attention_pallas(q, k_pages, v_pages, block_tables,
-                                      lengths)
-    return ref.paged_attention(q, k_pages, v_pages, block_tables, lengths)
+                                      lengths, starts)
+    return ref.paged_attention(q, k_pages, v_pages, block_tables, lengths,
+                               starts)

@@ -32,7 +32,8 @@ def gather_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    block_tables: jax.Array, lengths: jax.Array) -> jax.Array:
+                    block_tables: jax.Array, lengths: jax.Array,
+                    starts: jax.Array | None = None) -> jax.Array:
     """Paged decode-attention oracle: one query token per request, K/V
     gathered through the block table.
 
@@ -43,6 +44,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     entry (its keys are masked, the gather clamps the index).
     lengths: (B,) int32 — valid tokens per request (key positions
     >= lengths[b] masked, incl. the partially-filled last page).
+    starts: (B,) int32 or None (0) — each request's first key position (a
+    sliding window's); key positions below it are masked.
 
     Dtype discipline mirrors ``models.layers._sdpa`` exactly (f32 scores and
     softmax, probabilities cast back to q.dtype for the PV contraction) so
@@ -56,6 +59,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                    preferred_element_type=jnp.float32)
     kpos = jnp.arange(k.shape[1])
     mask = kpos[None, :] < lengths[:, None]              # (B, K)
+    if starts is not None:
+        mask &= kpos[None, :] >= starts[:, None]
     s = jnp.where(mask[:, None, None, :], s, -1e30)
     probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bgrk,bkgd->bgrd", probs, v,

@@ -57,7 +57,7 @@ from jax.profiler import TraceAnnotation
 from repro.config import ModelConfig, ParallelConfig
 from repro.models import transformer as T
 from repro.parallel import steps as S
-from repro.serving import BlockPool, telemetry
+from repro.serving import BlockPool, ring_pages, telemetry
 
 
 def sample_tokens(logits: jax.Array, key: jax.Array, temperature: float,
@@ -155,13 +155,17 @@ class Scheduler:
         if paged:
             if not T.supports_paged(cfg):
                 raise NotImplementedError(
-                    f"paged serving needs a pure-attention no-SWA pattern; "
-                    f"got {cfg.block_pattern} (window={cfg.window})")
+                    f"paged serving needs a pure-attention pattern; "
+                    f"got {cfg.period}")
             if block < 1 or chunk < 1:
                 raise ValueError(f"need block >= 1 and chunk >= 1, got "
                                  f"{block}/{chunk}")
             self.block, self.chunk = block, chunk
             self.n_pages = -(-max_len // block)      # block-table width
+            # windowed layers: a ring per slot, never wider than the table
+            windows = [c.window for c in cfg.period_cfgs if c.window]
+            self.ring_pages = min(ring_pages(max(windows), chunk, block),
+                                  self.n_pages) if windows else 0
             self.pool = BlockPool(
                 pool_blocks if pool_blocks is not None
                 else slots * self.n_pages, block)
@@ -210,7 +214,8 @@ class Scheduler:
         the seed for reproducible runs."""
         if self.paged:
             self.cache = T.init_paged_cache(self.cfg, self.pool.n_blocks,
-                                            self.block)
+                                            self.block, slots=self.slots,
+                                            ring_pages=self.ring_pages)
             self.pool.reset()
             self._tables = np.full((self.slots, self.n_pages), -1, np.int32)
         else:
@@ -352,7 +357,7 @@ class Scheduler:
         table = self.pool.table(st.req.rid, self.n_pages)[None]
         logits, self.cache = self._chunk_prefill(
             self.params, jnp.asarray(toks), self.cache, jnp.int32(lo),
-            jnp.asarray(table), jnp.int32(ln))
+            jnp.asarray(table), jnp.int32(ln), jnp.int32(slot))
         st.cursor = lo + ln
         if st.cursor < lp:
             return None
@@ -368,6 +373,13 @@ class Scheduler:
         if not self.paged:
             return 0, 0
         return self.pool.reserved_blocks, self.pool.live_blocks
+
+    def _expert_rows(self) -> list:
+        """The expert layers' per-slot counts of the last decode step, on
+        the device (``transformer.init_paged_cache``)."""
+        if not self.paged:
+            return []
+        return [c["expert_rows"] for c in self.cache if "expert_rows" in c]
 
     @telemetry.gc_spans()
     def run(self, requests: Sequence[Request] = (), *,
@@ -417,9 +429,9 @@ class Scheduler:
                 on_token(st.req.rid, tok)
 
         def log_tick(t: float, at: int, chunks: int, chunk_tokens: int,
-                     pages: tuple) -> None:
+                     pages: tuple, expert_rows: int = 0) -> None:
             telemetry.LOG.append(telemetry.Tick(t, at, chunks, chunk_tokens,
-                                                *pages))
+                                                *pages, expert_rows))
 
         while pending or active:
             now = time.perf_counter() - t0
@@ -507,8 +519,11 @@ class Scheduler:
                 else:
                     nxt, self.cache = self._decode(self.params, *args)
             with TraceAnnotation("serve.sync", tick=tick):
-                nxt = np.asarray(nxt)       # host sync = the stream point
+                # host sync = the stream point; the expert layers' counts
+                # come back with the tokens
+                nxt, held = jax.device_get((nxt, self._expert_rows()))
             t_sync, pages = time.perf_counter(), self._pages()
+            expert_rows = sum(int(h[:, decoding].sum()) for h in held)
             tick += 1
             try:
                 with TraceAnnotation("serve.emit", tick=tick - 1):
@@ -522,7 +537,8 @@ class Scheduler:
                             finish(slot)
             finally:
                 # logged even when a token callback ends the run
-                log_tick(t_sync, tick - 1, chunks, chunk_tokens, pages)
+                log_tick(t_sync, tick - 1, chunks, chunk_tokens, pages,
+                         expert_rows)
         jax.block_until_ready(self.cache)
         wall = time.perf_counter() - t0
         out = {

@@ -99,9 +99,8 @@ def main():
     if cfg.enc_dec:
         raise SystemExit("enc-dec serving: use examples/whisper_serve.py")
     if args.paged and not T.supports_paged(cfg):
-        raise SystemExit(f"--paged needs a pure-attention no-SWA arch; "
-                         f"{cfg.name} has pattern {cfg.block_pattern} "
-                         f"(window={cfg.window})")
+        raise SystemExit(f"--paged needs a pure-attention arch; "
+                         f"{cfg.name} has pattern {cfg.period}")
     # single-device layout as a first-class plan (the scheduler bridges it)
     plan = planner.ParallelPlan(mesh_shape=(1, 1), fsdp_axes=(), tp=1,
                                 grad="none", remat="none")
@@ -112,7 +111,7 @@ def main():
     if not args.paged and cfg.window is not None and max_len > cfg.window:
         raise SystemExit(f"prompt+gen {max_len} exceeds the "
                          f"attention window {cfg.window} (end-aligned slots; "
-                         f"--paged lifts the limit for no-SWA archs)")
+                         f"--paged lifts the limit)")
     sched = Scheduler(cfg, plan, params, slots=slots, max_len=max_len,
                       temperature=args.temperature, top_p=args.top_p,
                       seed=args.seed, paged=args.paged, block=args.block,

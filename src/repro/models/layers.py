@@ -239,22 +239,39 @@ def attention(p: Params, x: jax.Array, positions: jax.Array, cfg: ModelConfig, *
         # ---- paged cache: requests read/write the shared page arena
         # through their block-table rows (chains of fixed-size pages replace
         # the per-slot end-aligned row, so prompt+gen is bounded by pool
-        # capacity, not slot length).  SWA rings and paging don't compose.
-        assert cfg.window is None, "paged attention needs full (no-SWA) attention"
+        # capacity, not slot length).  ``block_tables`` is the pair
+        # (tables, rows): the pool's table rows and the slot of each row.
+        # A windowed layer keeps a ring of R pages per slot instead,
+        # (slots, R, Hkv, block, hd): its table follows the pool's (live
+        # where the pool's is) with logical page j in the slot's ring page
+        # j % R, and keys older than the window are never read.
+        tables, rows = block_tables
         ck, cv = cache                    # (n_blocks, Hkv, block, hd) arenas
+        ring_shape = ck.shape
+        if cfg.window is not None:
+            n_slots, ring = ck.shape[:2]
+            ck = ck.reshape((n_slots * ring,) + ck.shape[2:])
+            cv = cv.reshape(ck.shape)
+            j = jnp.arange(tables.shape[1])
+            tables = jnp.where(tables >= 0, rows[:, None] * ring + j % ring, -1)
         n_blocks, blk = ck.shape[0], ck.shape[2]
         if jnp.ndim(cache_pos) == 1:
             # decode: each request writes its token at page pos//block,
             # offset pos%block of its own chain; rows whose table entry is
             # -1 (parked/free slots) map OOB and the write drops
             pg, off = cache_pos // blk, cache_pos % blk
-            entry = jnp.take_along_axis(block_tables, pg[:, None], axis=1)[:, 0]
+            entry = jnp.take_along_axis(tables, pg[:, None], axis=1)[:, 0]
             phys = jnp.where(entry >= 0, entry, n_blocks)
             ck = ck.at[phys, :, off].set(k[:, 0].astype(ck.dtype), mode="drop")
             cv = cv.at[phys, :, off].set(v[:, 0].astype(cv.dtype), mode="drop")
             from repro.kernels.paged_attention import paged_attention
-            out = paged_attention(q[:, 0], ck, cv, block_tables,
-                                  cache_pos + 1)[:, None]
+            if cfg.window is None:
+                out = paged_attention(q[:, 0], ck, cv, tables, cache_pos + 1)
+            else:
+                out = paged_attention(
+                    q[:, 0], ck, cv, tables, cache_pos + 1,
+                    starts=jnp.maximum(cache_pos + 1 - cfg.window, 0))
+            out = out[:, None]
         else:
             # chunked prefill (one request, B=1): the chunk's tokens land at
             # absolute positions cache_pos..cache_pos+s-1 through the table,
@@ -269,18 +286,25 @@ def attention(p: Params, x: jax.Array, positions: jax.Array, cfg: ModelConfig, *
             # pad-token positions can run past the table width; an unguarded
             # gather would CLAMP to the last (live!) entry and scatter pad
             # K/V over real tokens — route them OOB so the write drops
-            n_pages = block_tables.shape[1]
+            n_pages = tables.shape[1]
             entry = jnp.where(pg < n_pages,
-                              block_tables[0, jnp.minimum(pg, n_pages - 1)],
-                              -1)
+                              tables[0, jnp.minimum(pg, n_pages - 1)], -1)
             phys = jnp.where(entry >= 0, entry, n_blocks)
             ck = ck.at[phys, :, off].set(k[0].astype(ck.dtype), mode="drop")
             cv = cv.at[phys, :, off].set(v[0].astype(cv.dtype), mode="drop")
             from repro.kernels.ref import gather_pages
-            out = _sdpa(q, gather_pages(ck, block_tables),
-                        gather_pages(cv, block_tables),
-                        causal=True, window=None, q_offset=cache_pos)
-        new_cache = (ck, cv)
+            first = 0
+            if cfg.window is not None:
+                # the ring's pages from the chunk's oldest key in its
+                # window on: they hold no later position yet
+                view = min(ring, n_pages)
+                first = jnp.clip((cache_pos - cfg.window + 1) // blk, 0,
+                                 n_pages - view)
+                tables = lax.dynamic_slice_in_dim(tables, first, view, axis=1)
+            out = _sdpa(q, gather_pages(ck, tables), gather_pages(cv, tables),
+                        causal=True, window=cfg.window,
+                        q_offset=cache_pos - first * blk)
+        new_cache = (ck.reshape(ring_shape), cv.reshape(ring_shape))
     elif cache is not None:
         ck, cv = cache  # (B, L, Hkv, hd), L sharded over model
         lk = ck.shape[1]

@@ -64,15 +64,24 @@ class MeshCtx:
 
 
 def moe_init(rng, cfg: ModelConfig) -> Params:
+    """The router over all ``n_experts`` and the weights of the experts held
+    here.  Each expert's weights are drawn from a key of its own, so a
+    share holds exactly what the whole layer holds of those experts."""
     e = cfg.moe
     d, ff = cfg.d_model, e.d_ff_expert
     ks = jax.random.split(rng, 5)
     scale = 1.0 / math.sqrt(d)
+    held = slice(e.first_held, e.first_held + e.held)
+
+    def experts(key, shape):
+        keys = jax.random.split(key, e.n_experts)[held]
+        return jax.vmap(lambda k: jax.random.normal(k, shape, _pdtype(cfg)))(keys)
+
     p = {
         "router": (jax.random.normal(ks[0], (d, e.n_experts), jnp.float32) * scale),
-        "w_gate": jax.random.normal(ks[1], (e.n_experts, d, ff), _pdtype(cfg)) * scale,
-        "w_up": jax.random.normal(ks[2], (e.n_experts, d, ff), _pdtype(cfg)) * scale,
-        "w_down": jax.random.normal(ks[3], (e.n_experts, ff, d), _pdtype(cfg)) / math.sqrt(ff),
+        "w_gate": experts(ks[1], (d, ff)) * scale,
+        "w_up": experts(ks[2], (d, ff)) * scale,
+        "w_down": experts(ks[3], (ff, d)) / math.sqrt(ff),
     }
     if e.n_shared_experts:
         sff = ff * e.n_shared_experts
@@ -85,15 +94,18 @@ def moe_init(rng, cfg: ModelConfig) -> Params:
     return p
 
 
-def _route(x_flat: jax.Array, router_w: jax.Array, top_k: int):
-    """Top-k routing with softmax-renormalized weights (f32)."""
+def _route(x_flat: jax.Array, router_w: jax.Array, e):
+    """Top-k routing in f32: scores over all experts (softmax or sigmoid),
+    the top_k picked, renormalised over the picked ones, times
+    ``routed_scale``.  Also the softmax probabilities for the load-balancing
+    loss."""
     logits = jnp.matmul(x_flat.astype(jnp.float32), router_w,
                         preferred_element_type=jnp.float32)
-    top_v, top_i = lax.top_k(logits, top_k)                 # (T, k)
-    weights = jax.nn.softmax(top_v, axis=-1)                # (T, k)
-    # aux load-balancing stats (Switch-style), returned for the loss
     probs = jax.nn.softmax(logits, axis=-1)
-    return top_i, weights, probs
+    scores = jax.nn.sigmoid(logits) if e.scoring == "sigmoid" else probs
+    top_v, top_i = lax.top_k(scores, e.top_k)               # (T, k)
+    top_v = top_v / jnp.sum(top_v, axis=-1, keepdims=True)
+    return top_i, top_v * e.routed_scale, probs
 
 
 def _expert_ffn(xs: jax.Array, group_sizes: jax.Array, w_gate, w_up, w_down, dtype):
@@ -123,7 +135,7 @@ def _moe_body_ep(x, router_w, w_gate, w_up, w_down, shared, *, cfg: ModelConfig,
         w_up = lax.all_gather(w_up, ax, axis=1, tiled=True)
         w_down = lax.all_gather(w_down, ax, axis=2, tiled=True)
 
-    top_i, weights, probs = _route(x_flat, router_w, e.top_k)
+    top_i, weights, probs = _route(x_flat, router_w, e)
 
     # --- local selection: my tokens' assignments to my experts -------------
     cap = int(math.ceil(t * e.top_k / ep * e.capacity_factor))
@@ -174,8 +186,10 @@ def _shared_ffn(x_flat, shared, cfg, model_axis):
 
 def _moe_body_tp(x, router_w, w_gate, w_up, w_down, shared, *, cfg: ModelConfig,
                  fsdp_axes: Tuple[str, ...], model_axis: Optional[str]):
-    """ff-sharded layout (expert count < axis size, e.g. Mixtral): every shard
-    computes ALL assignments (dropless) on a d_ff/ep slice."""
+    """ff-sharded layout (expert count < axis size, e.g. Mixtral), and the
+    single-device layer: the experts held here (``first_held`` on, as many
+    as the weights hold) compute every assignment routed to them (dropless),
+    on a d_ff/ep slice; assignments to experts held elsewhere add nothing."""
     e = cfg.moe
     dtype = _dtype(cfg)
     b, s, d = x.shape
@@ -187,27 +201,33 @@ def _moe_body_tp(x, router_w, w_gate, w_up, w_down, shared, *, cfg: ModelConfig,
         w_up = lax.all_gather(w_up, ax, axis=1, tiled=True)
         w_down = lax.all_gather(w_down, ax, axis=2, tiled=True)
 
-    top_i, weights, probs = _route(x_flat, router_w, e.top_k)
-    flat_e = top_i.reshape(-1)
+    top_i, weights, probs = _route(x_flat, router_w, e)
+    n_held = w_gate.shape[0]
+    flat_e = top_i.reshape(-1) - e.first_held
     flat_w = weights.reshape(-1)
+    # assignments to experts held elsewhere sort last, past every group
+    flat_e = jnp.where((flat_e >= 0) & (flat_e < n_held), flat_e, n_held)
     tok = jnp.arange(t * e.top_k) // e.top_k
 
     g_order = jnp.argsort(flat_e, stable=True)
     eid_s = flat_e[g_order]
     tok_s = tok[g_order]
     w_s = flat_w[g_order]
-    group_sizes = jnp.bincount(eid_s, length=e.n_experts).astype(jnp.int32)
+    group_sizes = jnp.bincount(eid_s, length=n_held).astype(jnp.int32)
 
     xs = x_flat[tok_s]
     ys = _expert_ffn(xs, group_sizes, w_gate, w_up, w_down, dtype)
-    out = jnp.zeros((t, d), jnp.float32).at[tok_s].add(
-        ys.astype(jnp.float32) * w_s[:, None])
+    out = jnp.zeros((t + 1, d), jnp.float32).at[
+        jnp.where(eid_s < n_held, tok_s, t)].add(
+        ys.astype(jnp.float32) * w_s[:, None])[:t]
 
     if shared is not None:
         out = out + _shared_ffn(x_flat, shared, cfg, model_axis=None)
     if model_axis is not None:
         out = lax.psum(out, model_axis)
-    return out.reshape(b, s, d).astype(dtype), probs
+    held = jnp.sum((flat_e < n_held).reshape(t, e.top_k), axis=-1,
+                   dtype=jnp.int32)
+    return out.reshape(b, s, d).astype(dtype), probs, held.reshape(b, s)
 
 
 def _moe_body_a2a(x, router_w, w_gate, w_up, w_down, shared, *,
@@ -228,7 +248,7 @@ def _moe_body_a2a(x, router_w, w_gate, w_up, w_down, shared, *,
     e_local = e.n_experts // dp
     x_flat = x.reshape(t, d)
 
-    top_i, weights, probs = _route(x_flat, router_w, e.top_k)
+    top_i, weights, probs = _route(x_flat, router_w, e)
     flat_e = top_i.reshape(-1)                                  # (T*k,)
     flat_w = weights.reshape(-1)
     dest = flat_e // e_local                                    # data shard
@@ -288,8 +308,10 @@ def _moe_body_a2a(x, router_w, w_gate, w_up, w_down, shared, *,
 
 def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
             ctx: Optional[MeshCtx], *, a2a_ep: Optional[bool] = None
-            ) -> Tuple[jax.Array, jax.Array]:
-    """Apply the MoE FFN.  Returns (output, router_probs_for_aux_loss)."""
+            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Apply the MoE FFN.  Returns (output, router_probs_for_aux_loss,
+    held): ``held`` (B, S) int32 counts each token's top_k experts that are
+    held here (all top_k where the layer holds every expert)."""
     e = cfg.moe
     shared = p.get("shared")
     if a2a_ep is None:
@@ -298,9 +320,11 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
     if ctx is None:
         # single-device path (smoke tests): same body, group of 1
         body = partial(_moe_body_tp, cfg=cfg, fsdp_axes=(), model_axis=None)
-        out, probs = body(x, p["router"], p["w_gate"], p["w_up"], p["w_down"], shared)
-        return out, probs
+        return body(x, p["router"], p["w_gate"], p["w_up"], p["w_down"], shared)
 
+    assert e.held == e.n_experts, \
+        "a mesh layer holds every expert; a held share runs on one device"
+    held = jnp.full(x.shape[:-1], e.top_k, jnp.int32)
     ep = ctx.model_size
     fsdp = tuple(a for a in ctx.fsdp_axes if a != ctx.model_axis)
     use_ep = e.n_experts % ep == 0 and e.n_experts >= ep
@@ -329,7 +353,8 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
             out_specs=(bspec, P(ctx.batch_axes, None)),
             check=False,
         )
-        return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"], shared)
+        return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"],
+                  shared) + (held,)
 
     if use_ep:
         espec_in = P(ctx.model_axis, fsdp if fsdp else None, None)    # (E, d, ff)
@@ -345,7 +370,7 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
 
         def body(xl, rw, wg, wu, wd, sh):
             return _moe_body_tp(xl, rw, wg, wu, wd, sh, cfg=cfg,
-                                fsdp_axes=fsdp, model_axis=ctx.model_axis)
+                                fsdp_axes=fsdp, model_axis=ctx.model_axis)[:2]
 
     shared_specs = None
     if shared is not None:
@@ -361,7 +386,8 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
         out_specs=(bspec, P(ctx.batch_axes, None)),
         check=False,
     )
-    return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"], shared)
+    return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"],
+              shared) + (held,)
 
 
 def load_balance_loss(probs: jax.Array, top_i_onehot_mean: Optional[jax.Array] = None) -> jax.Array:

@@ -1,9 +1,13 @@
 """Decoder-only LM assembly: dense / MoE / hybrid(Mamba2) / xLSTM families.
 
-Layers are grouped into the config's ``block_pattern`` period and scanned
-(``lax.scan``) over ``n_periods`` stacked parameter pytrees — this keeps the
-HLO size O(period) instead of O(n_layers), which matters both for compile
-time and for remat policy application (one ``jax.checkpoint`` per period).
+Layers are grouped into the config's period (``ModelConfig.period``: the
+``block_pattern``, or a longer run of layers where leading dense layers or
+per-layer attention kinds break it) and scanned (``lax.scan``) over
+``n_periods`` stacked parameter pytrees — this keeps the HLO size O(period)
+instead of O(n_layers), which matters both for compile time and for remat
+policy application (one ``jax.checkpoint`` per period).  Each position of
+the period runs its attention with its own config (``period_cfgs``: its
+window, and its rotary).
 
 Decode threads a per-layer cache pytree through the same scan (cache as scan
 xs, updated cache as scan ys).
@@ -59,34 +63,46 @@ def _block_apply(kind: str, p: Params, h: jax.Array, positions, cfg: ModelConfig
                  block_tables=None) -> Tuple[jax.Array, Optional[dict], jax.Array]:
     """Returns (h, new_cache, aux_loss_contribution).  ``block_tables``
     switches the attention cache to the paged page-arena view (pure
-    attention patterns only — ``supports_paged``)."""
+    attention patterns only — ``supports_paged``).  ``cfg`` is the layer's
+    own (``ModelConfig.period_cfgs``)."""
     aux = jnp.zeros((), jnp.float32)
     new_cache: Any = None
 
     if kind == "attn" or kind == "attn_moe":
         a_cache = cache.get("attn") if cache else None
-        x1 = L.apply_norm(p["ln1"], h, cfg)
+        # pre-norm blocks normalise each sublayer's input; post-norm blocks
+        # (EXAONE 4.0's QK-Reorder-LN) its output, before the residual add
+        pre = (lambda n, x: x) if cfg.post_norm else \
+            (lambda n, x: L.apply_norm(p[n], x, cfg))
+        post = (lambda n, x: L.apply_norm(p[n], x, cfg)) if cfg.post_norm \
+            else (lambda n, x: x)
+        x1 = pre("ln1", h)
         attn_out, a_new = L.attention(p["attn"], x1, positions, cfg,
                                       cache=a_cache, cache_pos=cache_pos,
                                       block_tables=block_tables, ctx=ctx)
+        new_cache = {"attn": a_new} if cache is not None else None
         if cfg.parallel_block:
             # command-r style: attn ∥ mlp read the same normed input
             if kind == "attn":
                 ffn_out = L.mlp(p["mlp"], x1, cfg, ctx=ctx)
             else:
-                ffn_out, probs = M.moe_ffn(p["moe"], x1, cfg, ctx)
+                ffn_out, probs, _ = M.moe_ffn(p["moe"], x1, cfg, ctx)
                 aux = aux + M.load_balance_loss(probs)
             h = h + attn_out + ffn_out
         else:
-            h = h + attn_out
-            x2 = L.apply_norm(p["ln2"], h, cfg)
+            h = h + post("ln1", attn_out)
+            x2 = pre("ln2", h)
             if kind == "attn":
-                h = h + L.mlp(p["mlp"], x2, cfg, ctx=ctx)
+                ffn_out = L.mlp(p["mlp"], x2, cfg, ctx=ctx)
             else:
-                moe_out, probs = M.moe_ffn(p["moe"], x2, cfg, ctx)
+                ffn_out, probs, held = M.moe_ffn(p["moe"], x2, cfg, ctx)
                 aux = aux + M.load_balance_loss(probs)
-                h = h + moe_out
-        new_cache = {"attn": a_new} if cache is not None else None
+                if cache is not None and "expert_rows" in cache:
+                    # each slot's assignments to the experts held here in
+                    # the last decode step; a prefill chunk keeps the count
+                    new_cache["expert_rows"] = held[:, 0] \
+                        if jnp.ndim(cache_pos) == 1 else cache["expert_rows"]
+            h = h + post("ln2", ffn_out)
 
     elif kind in ("mamba2", "mamba2_attn"):
         m_cache = cache.get("mamba") if cache else None
@@ -135,7 +151,7 @@ def init(rng, cfg: ModelConfig) -> Params:
     into the stacked (leading ``n_periods``) leaves, so peak memory stays at
     the parameter bytes plus one period's temporaries."""
     ks = jax.random.split(rng, 4)
-    period = cfg.block_pattern
+    period = cfg.period
 
     def one_period(prng):
         kr = jax.random.split(prng, len(period))
@@ -171,7 +187,7 @@ def forward(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
             embeddings: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
     """tokens: (B, S) int32 (or precomputed ``embeddings`` (B, S, d) for
     stub-frontend modalities).  Returns (logits_f32 (B, S, V), aux_loss)."""
-    period = cfg.block_pattern
+    period = tuple(zip(cfg.period, cfg.period_cfgs))
     h = embeddings if embeddings is not None else L.embed(params["embed"], tokens, cfg)
     b, s, _ = h.shape
     positions = jnp.arange(s)
@@ -179,8 +195,8 @@ def forward(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
 
     def period_fn(carry, layer_p):
         h, aux = carry
-        for i, kind in enumerate(period):
-            h, _, a = _block_apply(kind, layer_p[i], h, positions, cfg, ctx,
+        for i, (kind, lcfg) in enumerate(period):
+            h, _, a = _block_apply(kind, layer_p[i], h, positions, lcfg, ctx,
                                    None, None, shared_attn)
             aux = aux + a
         if ctx is not None:
@@ -217,7 +233,11 @@ def _constrain(h, ctx):
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                dtype=jnp.bfloat16) -> Any:
     """Cache pytree stacked over periods, mirroring the layer scan."""
-    period = cfg.block_pattern
+    period = cfg.period
+    if len({c.window for c in cfg.period_cfgs}) > 1:
+        raise NotImplementedError(
+            f"{cfg.name}: end-aligned cache rows hold one window for every "
+            f"layer; layers of different windows need the paged cache")
     kv_len = min(max_len, cfg.window) if cfg.window else max_len
 
     def one(kind):
@@ -251,25 +271,46 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 def supports_paged(cfg: ModelConfig) -> bool:
     """True when the paged KV-cache engine can serve this config: pure
     attention patterns (pages hold K/V lines only — recurrent state has no
-    per-position layout to page) with full attention (an SWA ring is itself
-    a reuse scheme; it does not compose with page chains)."""
-    return (not cfg.enc_dec and cfg.window is None
-            and all(k in ("attn", "attn_moe") for k in cfg.block_pattern))
+    per-position layout to page)."""
+    return (not cfg.enc_dec
+            and all(k in ("attn", "attn_moe") for k in cfg.period))
 
 
 def init_paged_cache(cfg: ModelConfig, n_blocks: int, block: int,
-                     dtype=jnp.bfloat16) -> Any:
+                     dtype=None, *, slots: int = 0,
+                     ring_pages: int = 0) -> Any:
     """Paged cache arena pytree, stacked over periods like ``init_cache``:
-    per attention layer one (K, V) pair of ``(n_blocks, kv_heads, block,
-    hd)`` pages shared by every request (``serving.BlockPool`` hands out the
-    blocks; requests address them through block tables)."""
+    per full-attention layer one (K, V) pair of ``(n_blocks, kv_heads,
+    block, hd)`` pages shared by every request (``serving.BlockPool`` hands
+    out the blocks; requests address them through block tables); per
+    windowed layer a ring of ``ring_pages`` pages for each of ``slots``
+    slots, ``(slots, ring_pages, kv_heads, block, hd)``, whose size does not
+    grow with the context.  An expert layer also keeps ``expert_rows``
+    (``(slots,)``, see ``_block_apply``).  K/V in ``dtype`` (default: the
+    activations' ``cfg.dtype``)."""
+    dtype = dtype or jnp.dtype(cfg.dtype)
     if not supports_paged(cfg):
         raise NotImplementedError(
-            f"paged KV cache needs a pure-attention, no-SWA pattern; got "
-            f"{cfg.block_pattern} (window={cfg.window})")
-    shp = (n_blocks, cfg.n_kv_heads, block, cfg.hd)
-    percell = tuple({"attn": (jnp.zeros(shp, dtype), jnp.zeros(shp, dtype))}
-                    for _ in cfg.block_pattern)
+            f"paged KV cache needs a pure-attention pattern; got "
+            f"{cfg.period}")
+    page = (cfg.n_kv_heads, block, cfg.hd)
+
+    def one(kind, lcfg):
+        if lcfg.window is None:
+            shp = (n_blocks,) + page
+        else:
+            if slots < 1 or ring_pages < 1:
+                raise ValueError(f"{cfg.name}: windowed layers need slots "
+                                 f"and ring_pages for their rings")
+            shp = (slots, ring_pages) + page
+        c = {"attn": (jnp.zeros(shp, dtype), jnp.zeros(shp, dtype))}
+        if kind == "attn_moe":
+            if slots < 1:
+                raise ValueError(f"{cfg.name}: expert layers need slots")
+            c["expert_rows"] = jnp.zeros((slots,), jnp.int32)
+        return c
+
+    percell = tuple(one(k, c) for k, c in zip(cfg.period, cfg.period_cfgs))
     return jax.tree.map(
         lambda x: jnp.broadcast_to(x[None], (cfg.n_periods,) + x.shape), percell)
 
@@ -280,7 +321,7 @@ def supports_fused_prefill(cfg: ModelConfig) -> bool:
     invisible.  Recurrent kinds (mamba2/mlstm/slstm) do support ``prefill``,
     but only for unpadded prompts whose length divides into the chunk scan —
     the serving scheduler falls back to the per-token loop for them."""
-    return all(k in ("attn", "attn_moe") for k in cfg.block_pattern)
+    return all(k in ("attn", "attn_moe") for k in cfg.period)
 
 
 def prefill(params: Params, tokens: jax.Array, cache: Any, cfg: ModelConfig, *,
@@ -293,7 +334,7 @@ def prefill(params: Params, tokens: jax.Array, cache: Any, cfg: ModelConfig, *,
     prompt lengths for right-padded batches — pad entries are causally
     invisible (attention patterns only; recurrent state would absorb them).
     Returns (last-position logits (B, V) f32, new_cache)."""
-    period = cfg.block_pattern
+    period = tuple(zip(cfg.period, cfg.period_cfgs))
     b, s = tokens.shape
     if length is not None:
         if not supports_fused_prefill(cfg):
@@ -315,8 +356,8 @@ def prefill(params: Params, tokens: jax.Array, cache: Any, cfg: ModelConfig, *,
     def period_fn(h, xs):
         layer_p, cache_p = xs
         new_caches = []
-        for i, kind in enumerate(period):
-            h, nc, _ = _block_apply(kind, layer_p[i], h, positions, cfg, ctx,
+        for i, (kind, lcfg) in enumerate(period):
+            h, nc, _ = _block_apply(kind, layer_p[i], h, positions, lcfg, ctx,
                                     cache_p[i], cache_pos, shared_attn)
             new_caches.append(nc)
         return h, tuple(new_caches)
@@ -334,28 +375,30 @@ def prefill(params: Params, tokens: jax.Array, cache: Any, cfg: ModelConfig, *,
 
 def prefill_paged(params: Params, tokens: jax.Array, cache: Any,
                   cfg: ModelConfig, *, pos0, block_tables: jax.Array,
-                  length=None, ctx=None,
+                  length=None, slot=0, ctx=None,
                   unroll: int = 1) -> Tuple[jax.Array, Any]:
     """One chunked-prefill slice: tokens (1, C) land at absolute positions
     ``pos0..pos0+C-1`` of one request's paged sequence (its pages named by
-    ``block_tables`` (1, P)), writing K/V into the arena and attending
-    causally over everything written so far.  ``length``: true token count
-    of a right-padded final chunk.  Returns (logits at the chunk's last real
-    token (1, V) f32, new_cache) — only the final chunk's logits are used
-    (they seed the first generated token)."""
-    period = cfg.block_pattern
+    ``block_tables`` (1, P); its windowed layers' ring is the one of
+    ``slot``), writing K/V into the arena and attending causally over
+    everything written so far (within the window, on a windowed layer).
+    ``length``: true token count of a right-padded final chunk.  Returns
+    (logits at the chunk's last real token (1, V) f32, new_cache) — only the
+    final chunk's logits are used (they seed the first generated token)."""
+    period = tuple(zip(cfg.period, cfg.period_cfgs))
     b, s = tokens.shape
     h = L.embed(params["embed"], tokens, cfg)
     positions = pos0 + jnp.arange(s)
     shared_attn = params.get("shared_attn")
+    pages = (block_tables, jnp.reshape(jnp.asarray(slot, jnp.int32), (1,)))
 
     def period_fn(h, xs):
         layer_p, cache_p = xs
         new_caches = []
-        for i, kind in enumerate(period):
-            h, nc, _ = _block_apply(kind, layer_p[i], h, positions, cfg, ctx,
+        for i, (kind, lcfg) in enumerate(period):
+            h, nc, _ = _block_apply(kind, layer_p[i], h, positions, lcfg, ctx,
                                     cache_p[i], pos0, shared_attn,
-                                    block_tables=block_tables)
+                                    block_tables=pages)
             new_caches.append(nc)
         return h, tuple(new_caches)
 
@@ -372,21 +415,24 @@ def decode_step(params: Params, token: jax.Array, cache: Any, pos: jax.Array,
     """One decode step.  token: (B,) int32; pos: scalar absolute position, or
     a (B,) vector of per-row positions (continuous-batching slots advance
     independently).  ``block_tables`` (B, P): paged mode — ``cache`` is the
-    page arena and each row addresses its own page chain.  Returns
-    (logits (B, V) f32, new_cache)."""
-    period = cfg.block_pattern
+    page arena, each row addresses its own page chain, and row b is slot b
+    (its windowed layers' ring).  Returns (logits (B, V) f32, new_cache)."""
+    period = tuple(zip(cfg.period, cfg.period_cfgs))
     h = L.embed(params["embed"], token[:, None], cfg)       # (B, 1, d)
     positions = pos[None] if jnp.ndim(pos) == 0 else pos[:, None]
-    cache_pos = pos if cfg.window is None else pos % cfg.window
+    paged = block_tables is not None
+    # paged layers take absolute positions; end-aligned rings reduce them
+    cache_pos = pos if cfg.window is None or paged else pos % cfg.window
+    pages = (block_tables, jnp.arange(token.shape[0])) if paged else None
     shared_attn = params.get("shared_attn")
 
     def period_fn(h, xs):
         layer_p, cache_p = xs
         new_caches = []
-        for i, kind in enumerate(period):
-            h, nc, _ = _block_apply(kind, layer_p[i], h, positions, cfg, ctx,
+        for i, (kind, lcfg) in enumerate(period):
+            h, nc, _ = _block_apply(kind, layer_p[i], h, positions, lcfg, ctx,
                                     cache_p[i], cache_pos, shared_attn,
-                                    block_tables=block_tables)
+                                    block_tables=pages)
             new_caches.append(nc)
         return h, tuple(new_caches)
 

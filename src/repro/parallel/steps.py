@@ -281,7 +281,8 @@ def make_chunk_prefill_step(cfg: ModelConfig, pcfg: ParallelConfig,
                             ctx: Optional[MeshCtx]) -> Callable:
     """Chunked-prefill step for the paged engine: one fixed-shape (1, chunk)
     slice of one request's prompt per call — K/V written into freshly
-    allocated pages through the block table, ``(last_logits, cache)`` back
+    allocated pages through the block table (and the windowed layers' ring
+    of the request's ``slot``), ``(last_logits, cache)`` back
     (``models.transformer.prefill_paged``).  Fixed chunk shape means ONE
     compile regardless of prompt length, and the per-call cost bounds the
     stall any admission can inflict on in-flight decodes
@@ -289,10 +290,12 @@ def make_chunk_prefill_step(cfg: ModelConfig, pcfg: ParallelConfig,
     if cfg.enc_dec:
         raise NotImplementedError("chunked prefill is decoder-only")
 
-    def chunk_prefill(params, tokens, cache, pos0, block_tables, length):
+    def chunk_prefill(params, tokens, cache, pos0, block_tables, length,
+                      slot=0):
         with jax.named_scope("chunk_prefill"):
             return T.prefill_paged(params, tokens, cache, cfg, pos0=pos0,
                                    block_tables=block_tables, length=length,
-                                   ctx=ctx, unroll=pcfg.scan_unroll)
+                                   slot=slot, ctx=ctx,
+                                   unroll=pcfg.scan_unroll)
 
     return chunk_prefill

@@ -5,6 +5,6 @@ The device-side pieces live next to their peers: the paged arena init in
 ``models.layers``, the Pallas decode kernel in ``kernels.paged_attention``,
 and the chunked-prefill scheduler integration in ``launch.scheduler``.
 """
-from .kvcache import BlockPool, PoolExhausted
+from .kvcache import BlockPool, PoolExhausted, ring_pages
 
-__all__ = ["BlockPool", "PoolExhausted"]
+__all__ = ["BlockPool", "PoolExhausted", "ring_pages"]

@@ -31,6 +31,11 @@ Allocation protocol (all methods O(pages touched)):
   * ``free(rid)`` — eviction: the whole chain returns to the free list and
     the reservation is released.
 
+Windowed (sliding-window) layers keep no page chain: each slot has a ring
+of ``ring_pages`` pages of its own (``models.layers.attention`` maps a
+row's logical page j to ring page j % R), so their memory does not grow
+with the context and the pool above counts full-attention pages only.
+
 The hypothesis property test (tests/test_paged.py) drives random staggered
 admit/ensure/free interleavings against the invariants: live chains are
 pairwise disjoint, free + live always partitions the pool, and reservations
@@ -41,6 +46,15 @@ from __future__ import annotations
 from typing import Dict, List
 
 import numpy as np
+
+
+def ring_pages(window: int, chunk: int, block: int) -> int:
+    """Pages of one slot's ring for a layer of ``window`` keys: a prefill
+    chunk's queries read back ``window - 1`` keys before the chunk while it
+    writes ``chunk`` positions, so the window plus one chunk, rounded up to
+    pages, plus the page a position may start in mid-page, never wrap onto a
+    key still read."""
+    return -(-(window + chunk) // block) + 1
 
 
 class PoolExhausted(RuntimeError):

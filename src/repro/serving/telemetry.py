@@ -24,8 +24,10 @@ profiler off a span costs under a microsecond of host time.
 
 Tick log.  One ``Tick`` per engine pass that did work, stamped with
 ``time.perf_counter()`` at the decode step's sync (at the tick's end on a
-tick that decodes nothing).  Records hold only numbers, never a device array,
-and the log keeps the newest ``MAX_TICKS``.  It is process-wide so that a
+tick that decodes nothing).  Its ``expert_rows`` is fetched from the device
+with the decode step's tokens, in the same ``serve.sync``.  Records hold
+only numbers, never a device array, and the log keeps the newest
+``MAX_TICKS``.  It is process-wide so that a
 reader can find it after the engine is gone: ``records(t0, t1)``.
 """
 from __future__ import annotations
@@ -47,6 +49,9 @@ class Tick(NamedTuple):
     chunk_tokens: int           # prompt tokens those chunks consumed
     reserved_pages: int         # BlockPool.reserved_blocks at the sync
     written_pages: int          # BlockPool.live_blocks at the sync
+    # routed assignments of the decode step's rows to the experts held
+    # here, over all expert layers (0 without experts or a decode step)
+    expert_rows: int = 0
 
 
 LOG: Deque[Tick] = deque(maxlen=MAX_TICKS)

@@ -20,14 +20,14 @@ cfg = ModelConfig(name="t", family="moe", n_layers=1, d_model=32, n_heads=4,
 params = moe_init(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (8, 4, 32))
 
-ref, ref_probs = moe_ffn(params, x, cfg, None)  # single-device oracle
+ref, ref_probs, _ = moe_ffn(params, x, cfg, None)  # single-device oracle
 
 mesh = make_mesh((2, 4), ("data", "model"))
 ctx = MeshCtx(mesh=mesh, batch_axes=("data",), model_axis="model",
               fsdp_axes=("data",))
 
 # EP layout: 8 experts over 4 shards (capacity_factor high => no drops)
-got, probs = jax.jit(lambda p, x: moe_ffn(p, x, cfg, ctx))(params, x)
+got, probs, _ = jax.jit(lambda p, x: moe_ffn(p, x, cfg, ctx))(params, x)
 np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-2, atol=2e-3)
 np.testing.assert_allclose(np.asarray(probs), np.asarray(ref_probs), rtol=1e-3, atol=1e-5)
 
@@ -37,13 +37,13 @@ cfg2 = ModelConfig(name="t2", family="moe", n_layers=1, d_model=32, n_heads=4,
                    dtype="float32",
                    moe=MoEConfig(n_experts=3, top_k=2, d_ff_expert=16))
 p2 = moe_init(jax.random.PRNGKey(2), cfg2)
-ref2, _ = moe_ffn(p2, x, cfg2, None)
-got2, _ = jax.jit(lambda p, x: moe_ffn(p, x, cfg2, ctx))(p2, x)
+ref2, _, _ = moe_ffn(p2, x, cfg2, None)
+got2, _, _ = jax.jit(lambda p, x: moe_ffn(p, x, cfg2, ctx))(p2, x)
 np.testing.assert_allclose(np.asarray(got2), np.asarray(ref2), rtol=2e-2, atol=2e-3)
 
 # gradients flow through the sharded path
 def loss(p):
-    out, _ = moe_ffn(p, x, cfg, ctx)
+    out, _, _ = moe_ffn(p, x, cfg, ctx)
     return jnp.sum(out ** 2)
 
 g = jax.jit(jax.grad(loss))(params)
@@ -56,6 +56,6 @@ print("MOE_OK")
 # a2a token-routing EP (§Perf H6) matches the oracle too
 ctx3 = MeshCtx(mesh=mesh, batch_axes=("data",), model_axis="model",
                fsdp_axes=(), moe_a2a_ep=True)
-got3, _ = jax.jit(lambda p, x: moe_ffn(p, x, cfg, ctx3))(params, x)
+got3, _, _ = jax.jit(lambda p, x: moe_ffn(p, x, cfg, ctx3))(params, x)
 np.testing.assert_allclose(np.asarray(got3), np.asarray(ref), rtol=2e-2, atol=2e-3)
 print("A2A_OK")

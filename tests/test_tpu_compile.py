@@ -80,6 +80,24 @@ def test_paged_attention_compiles(one_chip, arch, b, pages, n_blocks):
     assert table and table.group(1) == f"s32[{b},{pages}]"
 
 
+def test_windowed_paged_attention_compiles(one_chip):
+    """The k-exaone-236b-8l.chat cell's windowed layers: 48 slots' rings of
+    25 pages (1,200 pages), 8 KV heads of 8 queries, tables of 214 entries,
+    each row's first key position (its window's start) prefetched beside its
+    length; the table stays the custom call's first operand."""
+    b, pages, blk, hkv, rep, hd = 48, 214, 16, 8, 8, 128
+    arena = ((b * 25, hkv, blk, hd), jnp.bfloat16)
+    text = _compile_text(paged_attention_pallas, one_chip,
+                         ((b, hkv, rep, hd), jnp.bfloat16), arena, arena,
+                         ((b, pages), jnp.int32), ((b,), jnp.int32),
+                         ((b,), jnp.int32))
+    call = re.search(r"%paged_attention\.\d+ = .*?custom-call\((%[\w.\-]+)",
+                     text)
+    assert call, "no custom call named paged_attention"
+    table = re.search(re.escape(call.group(1)) + r" = (s32\[\d+,\d+\])", text)
+    assert table and table.group(1) == f"s32[{b},{pages}]"
+
+
 def test_matmul_acc_compiles(one_chip):
     n = 4096
     text = _compile_text(matmul_acc_pallas, one_chip,
